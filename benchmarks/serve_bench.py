@@ -41,9 +41,10 @@ runs ONLY this row plus its clean baseline (the CI smoke), merging the
 The **scaling rows** (DESIGN.md §13) replay the trace through the
 mesh-native sharded engine on 1/2/4/8 virtual CPU devices — per-device
 weight + KV bytes, collective bytes per decode step, token identity vs
-the 1-device mesh.  They run in a subprocess (``serve_scaling.py``; the
-device-count flag must precede jax init) and land as the ``scaling``
-section of ``BENCH_serve.json``.
+the 1-device mesh.  On CPU they run in a subprocess (``serve_scaling.py``;
+the device-count flag must precede jax init), on a TPU host in this
+process over the chips present, and land as the ``scaling`` section of
+``BENCH_serve.json``.
 
 The **prefix rows** (DESIGN.md §14) replay a shared-system-prompt trace
 — every request repeats the same ``SYS_LEN``-token system prompt before
@@ -356,8 +357,8 @@ def run():
     # prefix caching: shared-system-prompt trace, sharing vs no-sharing
     pfx, pfx_rows = _prefix_doc_and_rows(qm, packed, cfg.vocab_size)
 
-    # mesh scaling: the sharded engine on 1/2/4/8 virtual devices
-    # (subprocess — XLA's device-count flag must precede jax init)
+    # mesh scaling: the sharded engine on 1/2/4/8 devices (virtual CPU
+    # devices in a subprocess; on TPU the chips present, in-process)
     from benchmarks import serve_scaling
     scaling = serve_scaling.run_scaling()
 

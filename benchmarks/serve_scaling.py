@@ -16,9 +16,12 @@ Runs the serve_bench mixed-length trace through the mesh-native Engine on
 - token identity against the (1,1) mesh (GSPMD must not change a single
   sampled token).
 
-Virtual devices need ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-set BEFORE jax initializes, which the benchmark runner's process has long
-passed — so the sweep runs in a subprocess (same pattern as
+On a TPU host the sweep runs in this process over ``jax.devices()``
+(the meshes that fit the chips present): a chip belongs to one process,
+so a child could not reach it.  On CPU the sweep needs
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` set BEFORE jax
+initializes, which the benchmark runner's process has long passed — so
+there it runs in a CPU-only subprocess (same pattern as
 ``tests/test_sharded_serving.py``) and hands back one JSON document.
 ``serve_bench.run()`` merges it as the ``scaling`` section of
 ``BENCH_serve.json``; on a CPU container tokens/s across virtual devices
@@ -34,104 +37,111 @@ import sys
 
 MESHES = ((1, 1), (1, 2), (2, 2), (2, 4))
 
-_CHILD = r"""
-import json
-import os
-import time
-
-import numpy as np
-import jax
-
-from repro import hlo_analysis
-from repro.configs import get_config
-from repro.core.quantizer import QuantConfig
-from repro.launch.mesh import make_serving_mesh
-from repro.models import build_model
-from repro.serve.engine import Engine, ServeConfig
-from repro.serve.kv_cache import pages_for
-from repro.serve.quantized import QuantizedModel, quantize_lm_packed
-
-assert len(jax.devices()) == 8, jax.devices()
-FAST = bool(int(os.environ.get("BENCH_FAST", "0")))
 PAGE_SIZE = 16
 MAX_BATCH = 4
 MAX_LEN = 192
-MAX_NEW = 8 if FAST else 16
 TRACE = [8, 40, 16, 96, 24, 64, 8, 120, 32, 12, 80, 18]
-N_REQ = 6 if FAST else len(TRACE)
-MESHES = json.loads(os.environ["SCALING_MESHES"])
-
-cfg = get_config("llama-micro")
-params = build_model(cfg).init(jax.random.PRNGKey(0))
-# the w4a4kv4 deployment point — the stack the tentpole shards
-qcfg = QuantConfig(w_bits=4, a_bits=4, group_size=32, lwc=False, kv_bits=4)
-packed = quantize_lm_packed(params, cfg, qcfg)
-qm = QuantizedModel(cfg, qcfg, kernel_mode="ref", flash_block_kv=PAGE_SIZE)
-rng = np.random.default_rng(0)
-prompts = [rng.integers(0, cfg.vocab_size, n) for n in TRACE[:N_REQ]]
-lens = [len(p) + MAX_NEW for p in prompts]
-num_pages = MAX_BATCH * pages_for(int(np.percentile(lens, 95)), PAGE_SIZE)
 
 
-def trace_pass(eng):
-    reqs = [eng.submit(p) for p in prompts]
-    t0 = time.monotonic()
-    eng.run(max_steps=4000)
-    dt = time.monotonic() - t0
-    toks = sum(len(r.out_tokens) for r in reqs)
-    return [tuple(int(t) for t in r.out_tokens) for r in reqs], toks, dt
+def sweep(meshes) -> dict:
+    """Serve the trace on each ``(data, model)`` mesh of ``meshes`` in this
+    process; returns the ``scaling`` doc."""
+    import time
 
+    import jax
+    import numpy as np
 
-def bench(dm):
-    scfg = ServeConfig(max_batch=MAX_BATCH, max_len=MAX_LEN, max_new=MAX_NEW,
-                       prefill_bucket=32, paged=True, page_size=PAGE_SIZE,
-                       num_pages=num_pages)
-    eng = Engine(qm, packed, scfg, mesh=make_serving_mesh(*dm))
-    trace_pass(eng)                       # warmup (compiles)
-    outs, toks, dt = trace_pass(eng)
-    eng._kv.verify()
-    rep = eng.memory_report()
-    with eng._bound():
-        hlo = eng._decode.lower(
-            eng.params, eng._last_tok, eng._kv.cache, eng._idle_keys,
-            eng._zero_poison).compile().as_text()
-    coll = hlo_analysis.analyze_hlo(hlo)["collectives"]
-    return outs, {
-        "mesh": list(dm), "device_count": rep["device_count"],
-        "tokens_per_s": toks / dt, "wall_s": dt, "new_tokens": toks,
-        "weight_bytes_per_device": rep["weight_bytes_per_device"],
-        "kv_bytes_per_device": rep["kv_bytes_per_device"],
-        "decode_collective_bytes_per_step": coll["total_bytes"],
-        "decode_collective_ops": {k: v for k, v in
-                                  coll["count_by_kind"].items() if v},
-    }
+    from repro import hlo_analysis
+    from repro.configs import get_config
+    from repro.core.quantizer import QuantConfig
+    from repro.launch.mesh import make_serving_mesh
+    from repro.models import build_model
+    from repro.serve.engine import Engine, ServeConfig
+    from repro.serve.kv_cache import pages_for
+    from repro.serve.quantized import QuantizedModel, quantize_lm_packed
 
+    fast = bool(int(os.environ.get("BENCH_FAST", "0")))
+    max_new = 8 if fast else 16
+    n_req = 6 if fast else len(TRACE)
+    cfg = get_config("llama-micro")
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    # the w4a4kv4 deployment point — the stack the tentpole shards
+    qcfg = QuantConfig(w_bits=4, a_bits=4, group_size=32, lwc=False,
+                       kv_bits=4)
+    packed = quantize_lm_packed(params, cfg, qcfg)
+    qm = QuantizedModel(cfg, qcfg, kernel_mode="ref",
+                        flash_block_kv=PAGE_SIZE)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in TRACE[:n_req]]
+    lens = [len(p) + max_new for p in prompts]
+    num_pages = MAX_BATCH * pages_for(int(np.percentile(lens, 95)),
+                                      PAGE_SIZE)
 
-base_outs = None
-rows = []
-for dm in MESHES:
-    outs, row = bench(tuple(dm))
-    if base_outs is None:
-        base_outs = outs
-    row["token_identical"] = outs == base_outs
-    rows.append(row)
-print("SCALING-JSON:" + json.dumps({
-    "quant": "w4a4g32kv4", "trace_prompt_lens": [int(len(p))
-                                                 for p in prompts],
-    "max_new": MAX_NEW, "rows": rows}))
-"""
+    def trace_pass(eng):
+        reqs = [eng.submit(p) for p in prompts]
+        t0 = time.monotonic()
+        eng.run(max_steps=4000)
+        dt = time.monotonic() - t0
+        toks = sum(len(r.out_tokens) for r in reqs)
+        return [tuple(int(t) for t in r.out_tokens) for r in reqs], toks, dt
+
+    def bench(dm):
+        scfg = ServeConfig(max_batch=MAX_BATCH, max_len=MAX_LEN,
+                           max_new=max_new, prefill_bucket=32, paged=True,
+                           page_size=PAGE_SIZE, num_pages=num_pages)
+        eng = Engine(qm, packed, scfg, mesh=make_serving_mesh(*dm))
+        trace_pass(eng)                       # warmup (compiles)
+        outs, toks, dt = trace_pass(eng)
+        eng._kv.verify()
+        rep = eng.memory_report()
+        with eng._bound():
+            hlo = eng._decode.lower(
+                eng.params, eng._last_tok, eng._kv.cache, eng._idle_keys,
+                eng._zero_poison).compile().as_text()
+        coll = hlo_analysis.analyze_hlo(hlo)["collectives"]
+        return outs, {
+            "mesh": list(dm), "device_count": rep["device_count"],
+            "tokens_per_s": toks / dt, "wall_s": dt, "new_tokens": toks,
+            "weight_bytes_per_device": rep["weight_bytes_per_device"],
+            "kv_bytes_per_device": rep["kv_bytes_per_device"],
+            "decode_collective_bytes_per_step": coll["total_bytes"],
+            "decode_collective_ops": {k: v for k, v in
+                                      coll["count_by_kind"].items() if v},
+        }
+
+    base_outs = None
+    rows = []
+    for dm in meshes:
+        outs, row = bench(tuple(dm))
+        if base_outs is None:
+            base_outs = outs
+        row["token_identical"] = outs == base_outs
+        rows.append(row)
+    dev = jax.devices()[0]
+    return {"quant": "w4a4g32kv4", "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "trace_prompt_lens": [int(len(p)) for p in prompts],
+            "max_new": max_new, "rows": rows}
 
 
 def run_scaling() -> dict:
-    """Spawn the 8-virtual-device sweep; returns the ``scaling`` doc."""
+    """The mesh sweep; returns the ``scaling`` doc.  On TPU it runs in
+    this process over the chips present; elsewhere in a CPU child with 8
+    virtual devices."""
+    import jax
+    if jax.default_backend() == "tpu":
+        n = len(jax.devices())
+        return sweep([m for m in MESHES if m[0] * m[1] <= n])
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["SCALING_MESHES"] = json.dumps([list(m) for m in MESHES])
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root]
         + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p])
-    proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
+    child = ("import json; from benchmarks import serve_scaling as s; "
+             "print('SCALING-JSON:' + json.dumps(s.sweep(s.MESHES)))")
+    proc = subprocess.run([sys.executable, "-c", child], env=env,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
         raise RuntimeError(

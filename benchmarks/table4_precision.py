@@ -17,7 +17,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import equivalence as eq
 from repro.core.calibration import CalibConfig, quantize_dense_model
@@ -43,7 +42,7 @@ def merge_error_stats():
             w = jax.random.normal(jax.random.fold_in(key, 1), (H, H))
             x = jax.random.normal(jax.random.fold_in(key, 2), (NTOK, H))
             e32.append(float(eq.merge_error(x, w, a, jnp.float32)))
-            with enable_x64():
+            with jax.enable_x64(True):
                 e64.append(float(eq.merge_error(
                     jnp.asarray(np.asarray(x), jnp.float64),
                     jnp.asarray(np.asarray(w), jnp.float64),
@@ -75,7 +74,7 @@ def run(arch: str = "llama-micro"):
         us = (time.perf_counter() - t0) * 1e6
         rows.append((f"table4/calib/{name}", us,
                      f"ppl={common.ppl(model, q, test):.4f}"))
-    with enable_x64():
+    with jax.enable_x64(True):
         t0 = time.perf_counter()
         q, _ = quantize_dense_model(
             params, cfg, qcfg,

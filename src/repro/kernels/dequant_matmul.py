@@ -60,7 +60,10 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *, bits: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = _unpack_block(p_ref[...], bits, bk).astype(jnp.float32)
+    # codes < 2**bits: the int32 hop is exact, and Mosaic has no
+    # uint32 -> f32 cast
+    codes = _unpack_block(p_ref[...], bits, bk).astype(jnp.int32).astype(
+        jnp.float32)
     scale = s_ref[...].astype(jnp.float32)       # (bk // group, bn)
     zp = z_ref[...].astype(jnp.float32)
     gk = bk // scale.shape[0]

@@ -17,8 +17,9 @@ never exists on this path (jaxpr-pinned, like the decode kernel's).
 Layout and grid:
 
     q         (B, Hkv, C, G, D)   GQA groups folded next to their KV head;
-                                  flattened in-kernel to (C*G, D) rows where
-                                  row r is chunk token r // G
+                                  flattened outside the kernel to (C*G, D)
+                                  rows per head where row r is chunk token
+                                  r // G
     k / v     (B, S, Hkv, Dk)     the cache tensors, untouched
                                   (Dk = D//2 packed int4, else D)
     k/v scale                     (B, S, Hkv) f32 for kv8;
@@ -28,7 +29,10 @@ Layout and grid:
     chunk_len (B,) int32          valid chunk rows per sequence
                                   (scalar-prefetch; rows past it are pads)
 
-    grid (B, Hkv, ceil(S / block_kv))   — KV tiles innermost
+    grid (B, ceil(S / block_kv))   — KV tiles innermost; ALL heads per
+                                     step (the block layout of
+                                     ``flash_decode``, for the same Mosaic
+                                     block rule)
 
 The KV grid is **length-masked** on the chunk's end: tile ``t`` of sequence
 ``b`` only computes when ``t * block_kv < offset[b] + chunk_len[b]``, and
@@ -56,8 +60,9 @@ Paged variant (``flash_prefill_paged``): the cache is the page pool of
 per-sequence page tables — and the KV grid walks the page table exactly
 like ``flash_decode_paged`` (both scalars AND the table are scalar-prefetch
 operands; the page gather lives in the BlockSpec index map; one tile ==
-one page).  The kernel body is shared verbatim with the linear variant, so
-the two layouts cannot drift.
+one page).  The kernel body is shared verbatim with the linear variant, and
+the per-(tile, head) math is ``flash_decode.attend_tile``, so neither the
+two layouts nor decode and prefill can drift.
 """
 from __future__ import annotations
 
@@ -68,19 +73,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quantize_pack import (KV_BLOCK, kv4_check_head_dim,
-                                         kv4_dequant)
+from repro.kernels.flash_decode import (NEG_INF, VMEM_LIMIT, attend_tile,
+                                        bind_absent_scales, head_scales,
+                                        kv_format, kv_specs)
+from repro.kernels.quantize_pack import KV_BLOCK, kv4_check_head_dim
 
-NEG_INF = -1e30
 DEFAULT_BLOCK_KV = 512
 
 
 def _kernel(offs_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
             m_ref, l_ref, acc_ref, *, block_kv: int, n_tiles: int,
-            chunk: int, g: int, scale: float, kv_bits: int):
+            g: int, scale: float, kv_bits: int):
     b = pl.program_id(0)
-    t = pl.program_id(2)
-    r = chunk * g
+    t = pl.program_id(1)
+    r = q_ref.shape[2]
 
     @pl.when(t == 0)
     def _init():
@@ -96,46 +102,57 @@ def _kernel(offs_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     # gating here keeps their prefix out of the DMA/compute stream
     @pl.when((t * block_kv < off + cl) & (cl > 0))
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32).reshape(r, -1)   # (C*G, D)
-        if kv_bits == 4:
-            # in-register nibble unpack + block-32 microscaling dequant:
-            # codes tile (block_kv, D//2), scales tile (block_kv, D//32)
-            k = kv4_dequant(k_ref[0, :, 0, :], ks_ref[0, :, 0, :])
-            v = kv4_dequant(v_ref[0, :, 0, :], vs_ref[0, :, 0, :])
-        else:
-            k = k_ref[0, :, 0, :].astype(jnp.float32)        # (block_kv, D)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if kv_bits == 8:
-            # in-register dequant: int8 codes * per-(token, head) f32 scale
-            k = k * ks_ref[...].reshape(block_kv, 1)
-            v = v * vs_ref[...].reshape(block_kv, 1)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (C*G, block_kv)
         kv_pos = t * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (r, block_kv), 1)
         row_tok = jax.lax.broadcasted_iota(jnp.int32, (r, block_kv), 0) // g
         # causal across the chunk/prefix boundary + pad-row masking
-        s = jnp.where((kv_pos <= off + row_tok) & (row_tok < cl), s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        valid = (kv_pos <= off + row_tok) & (row_tok < cl)
+        for h in range(q_ref.shape[1]):
+            ks, vs = head_scales(ks_ref, vs_ref, h, kv_bits, 0)
+            m, l, acc = attend_tile(
+                q_ref[0, h].astype(jnp.float32), k_ref[0, :, h, :],
+                v_ref[0, :, h, :], ks, vs, valid,
+                m_ref[h], l_ref[h], acc_ref[h], scale=scale, kv_bits=kv_bits)
+            m_ref[h] = m
+            l_ref[h] = l
+            acc_ref[h] = acc
 
     @pl.when(t == n_tiles - 1)
     def _done():
         # pad rows (row_tok >= chunk_len) are fully masked, but masked
         # scores all equal NEG_INF so p == exp(0) == 1 accumulates junk —
         # zero them explicitly (valid-row values pass through unchanged)
-        live = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0) // g < cl
+        live = jax.lax.broadcasted_iota(jnp.int32, (1, r, 1), 1) // g < cl
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         out = jnp.where(live, out, 0.0)
-        o_ref[0, 0] = out.reshape(chunk, g, -1).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _call(kernel, in_specs, n_tiles, q, scalars, args, interpret):
+    """Shared pallas_call of both layouts: q (B, Hkv, C, G, D) runs as
+    (B, Hkv, C*G, D) rows, one (Hkv, C*G, D) block per sequence."""
+    bsz, hkv, c, g, d = q.shape
+    r = c * g
+    out_map = lambda b, t, *_: (b, 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(bsz, n_tiles),
+        in_specs=[pl.BlockSpec((1, hkv, r, d), out_map)] + in_specs,
+        out_specs=pl.BlockSpec((1, hkv, r, d), out_map),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, r, 1), jnp.float32),   # running max
+            pltpu.VMEM((hkv, r, 1), jnp.float32),   # running denominator
+            pltpu.VMEM((hkv, r, d), jnp.float32),   # output accumulator
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bsz, hkv, r, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(*scalars, q.reshape(bsz, hkv, r, d), *args)
+    return out.reshape(bsz, hkv, c, g, d)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_kv",
@@ -159,80 +176,37 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     bsz, hkv, c, g, d = q.shape
     s = k.shape[1]
     assert s % block_kv == 0, (s, block_kv)
-    quantized = k_scale is not None
-    assert quantized == (v_scale is not None)
-    packed = quantized and k_scale.ndim == k.ndim
-    kv_bits = 4 if packed else (8 if quantized else 16)
-    dk = d // 2 if packed else d
+    kv_bits = kv_format(k, k_scale, v_scale)
+    dk = d // 2 if kv_bits == 4 else d
     assert k.shape == v.shape == (bsz, s, hkv, dk), \
         (q.shape, k.shape, v.shape, kv_bits)
-    if packed:
+    if kv_bits == 4:
         kv4_check_head_dim(d)
         assert k_scale.shape == v_scale.shape == (bsz, s, hkv, d // KV_BLOCK)
-    elif quantized:
+    elif kv_bits == 8:
         assert k_scale.shape == v_scale.shape == (bsz, s, hkv)
     n_tiles = s // block_kv
     scale = scale if scale is not None else d ** -0.5
-    offset = offset.astype(jnp.int32)
-    chunk_len = chunk_len.astype(jnp.int32)
 
-    def _last(offs, lens, b):
+    def tile_idx(b, t, offs, lens):
         # clamp out-of-range tiles to the last valid tile: a repeated block
         # index is not re-fetched, so masked tiles move no HBM bytes.
         # cl == 0 rows attend nothing — clamp them to tile 0 (one DMA).
         total = jnp.where(lens[b] > 0, offs[b] + lens[b], 0)
-        return jnp.maximum(pl.cdiv(total, block_kv) - 1, 0)
+        return jnp.minimum(t, jnp.maximum(pl.cdiv(total, block_kv) - 1, 0))
 
-    def kv_map(b, h, t, offs, lens):
-        return (b, jnp.minimum(t, _last(offs, lens, b)), h, 0)
-
-    def scale_map(b, h, t, offs, lens):
-        return (b, jnp.minimum(t, _last(offs, lens, b)), h)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, c, g, d), lambda b, h, t, offs, lens:
-                     (b, h, 0, 0, 0)),
-        pl.BlockSpec((1, block_kv, 1, dk), kv_map),
-        pl.BlockSpec((1, block_kv, 1, dk), kv_map),
-    ]
-    args = [q, k, v]
-    if packed:
-        # 4D block-scale tile rides the same clamped kv_map as the codes
-        sspec = pl.BlockSpec((1, block_kv, 1, d // KV_BLOCK), kv_map)
-        in_specs += [sspec, sspec]
-        args += [k_scale, v_scale]
-    elif quantized:
-        in_specs += [pl.BlockSpec((1, block_kv, 1), scale_map),
-                     pl.BlockSpec((1, block_kv, 1), scale_map)]
-        args += [k_scale, v_scale]
-
+    in_specs, args = kv_specs(
+        kv_bits, block_kv, hkv, d,
+        lambda b, t, offs, lens: (b, tile_idx(b, t, offs, lens), 0, 0),
+        lambda b, t, offs, lens: (b, tile_idx(b, t, offs, lens), 0),
+        k, v, k_scale, v_scale)
     body = functools.partial(_kernel, block_kv=block_kv, n_tiles=n_tiles,
-                             chunk=c, g=g, scale=scale, kv_bits=kv_bits)
-    if not quantized:
-        # keep one kernel body: bind the absent scale refs to None
-        body = functools.partial(
-            lambda offs, lens, qr, kr, vr, o, m, l, a, *, inner:
-            inner(offs, lens, qr, kr, vr, None, None, o, m, l, a),
-            inner=body)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bsz, hkv, n_tiles),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, c, g, d), lambda b, h, t, offs, lens:
-                               (b, h, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((c * g, 1), jnp.float32),   # running max
-            pltpu.VMEM((c * g, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((c * g, d), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, hkv, c, g, d), q.dtype),
-        interpret=interpret,
-    )(offset, chunk_len, *args)
+                             g=g, scale=scale, kv_bits=kv_bits)
+    if kv_bits == 16:
+        body = bind_absent_scales(body, 2)
+    return _call(body, in_specs, n_tiles, q,
+                 [offset.astype(jnp.int32), chunk_len.astype(jnp.int32)],
+                 args, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -257,25 +231,19 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
     num_pages, page_size = k.shape[0], k.shape[1]
     n_tiles = page_table.shape[1]
     assert page_table.shape == (bsz, n_tiles), (page_table.shape, bsz)
-    quantized = k_scale is not None
-    assert quantized == (v_scale is not None)
-    packed = quantized and k_scale.ndim == k.ndim
-    kv_bits = 4 if packed else (8 if quantized else 16)
-    dk = d // 2 if packed else d
+    kv_bits = kv_format(k, k_scale, v_scale)
+    dk = d // 2 if kv_bits == 4 else d
     assert k.shape == v.shape == (num_pages, page_size, hkv, dk), \
         (q.shape, k.shape, v.shape, kv_bits)
-    if packed:
+    if kv_bits == 4:
         kv4_check_head_dim(d)
         assert k_scale.shape == v_scale.shape == \
             (num_pages, page_size, hkv, d // KV_BLOCK)
-    elif quantized:
+    elif kv_bits == 8:
         assert k_scale.shape == v_scale.shape == (num_pages, page_size, hkv)
     scale = scale if scale is not None else d ** -0.5
-    offset = offset.astype(jnp.int32)
-    chunk_len = chunk_len.astype(jnp.int32)
-    page_table = page_table.astype(jnp.int32)
 
-    def _page(b, t, offs, lens, pt):
+    def page(b, t, offs, lens, pt):
         # the page GATHER lives in the index map (see flash_decode_paged):
         # out-of-range tiles repeat the last valid pool page so Pallas
         # skips the DMA; cl == 0 rows clamp to logical page 0, and
@@ -284,58 +252,20 @@ def flash_prefill_paged(q: jax.Array, k: jax.Array, v: jax.Array,
         last = jnp.maximum(pl.cdiv(total, page_size) - 1, 0)
         return jnp.maximum(pt[b, jnp.minimum(t, last)], 0)
 
-    def kv_map(b, h, t, offs, lens, pt):
-        return (_page(b, t, offs, lens, pt), 0, h, 0)
-
-    def scale_map(b, h, t, offs, lens, pt):
-        return (_page(b, t, offs, lens, pt), 0, h)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, c, g, d), lambda b, h, t, offs, lens, pt:
-                     (b, h, 0, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, dk), kv_map),
-        pl.BlockSpec((1, page_size, 1, dk), kv_map),
-    ]
-    args = [q, k, v]
-    if packed:
-        # 4D block-scale page gathered by the same kv_map as the codes
-        sspec = pl.BlockSpec((1, page_size, 1, d // KV_BLOCK), kv_map)
-        in_specs += [sspec, sspec]
-        args += [k_scale, v_scale]
-    elif quantized:
-        in_specs += [pl.BlockSpec((1, page_size, 1), scale_map),
-                     pl.BlockSpec((1, page_size, 1), scale_map)]
-        args += [k_scale, v_scale]
-
+    in_specs, args = kv_specs(
+        kv_bits, page_size, hkv, d,
+        lambda b, t, offs, lens, pt: (page(b, t, offs, lens, pt), 0, 0, 0),
+        lambda b, t, offs, lens, pt: (page(b, t, offs, lens, pt), 0, 0),
+        k, v, k_scale, v_scale)
     # one tile == one page: reuse the linear kernel body verbatim so the
     # two layouts cannot diverge in op order
     body = functools.partial(_kernel, block_kv=page_size, n_tiles=n_tiles,
-                             chunk=c, g=g, scale=scale, kv_bits=kv_bits)
-    if not quantized:
-        body = functools.partial(
-            lambda offs, lens, qr, kr, vr, o, m, l, a, *, inner:
-            inner(offs, lens, qr, kr, vr, None, None, o, m, l, a),
-            inner=body)
+                             g=g, scale=scale, kv_bits=kv_bits)
+    if kv_bits == 16:
+        body = bind_absent_scales(body, 2)
     kernel = functools.partial(
         lambda offs, lens, pt, *rest, inner: inner(offs, lens, *rest),
         inner=body)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(bsz, hkv, n_tiles),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, c, g, d),
-                               lambda b, h, t, offs, lens, pt:
-                               (b, h, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((c * g, 1), jnp.float32),   # running max
-            pltpu.VMEM((c * g, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((c * g, d), jnp.float32),   # output accumulator
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, hkv, c, g, d), q.dtype),
-        interpret=interpret,
-    )(offset, chunk_len, page_table, *args)
+    return _call(kernel, in_specs, n_tiles, q,
+                 [offset.astype(jnp.int32), chunk_len.astype(jnp.int32),
+                  page_table.astype(jnp.int32)], args, interpret)

@@ -139,20 +139,19 @@ def w8a8_matmul(x: jax.Array, w_q: jax.Array, w_scale: jax.Array, *,
     )(x, w_q, ws2d)
 
 
-def _w4a8_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *, bits: int,
-                 a_bits: int, group: int, bk: int, n_k: int):
+def _w4a8_kernel(x_ref, as_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
+                 bits: int, a_bits: int, group: int, bk: int, n_k: int):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # fused activation prologue: per-(token, K-slab) dynamic symmetric quant
-    # into int8 lanes (a_bits=4 uses the [-8, 7] sub-range of the lane)
-    xf = x_ref[...].astype(jnp.float32)
+    # fused activation prologue: this K-slab's codes in int8 lanes, on the
+    # token's whole-row scale (a_bits=4 uses the [-8, 7] sub-range)
     qmax = 2.0 ** (a_bits - 1) - 1.0
-    bound = jnp.maximum(jnp.max(jnp.abs(xf), axis=1, keepdims=True), 1e-8)
-    a_scale = bound / qmax
+    a_scale = as_ref[...]                          # (bm, 1) f32
+    xf = x_ref[...].astype(jnp.float32)
     x_q = jnp.clip(jnp.round(xf / a_scale), -qmax - 1.0, qmax
                    ).astype(jnp.int8)
 
@@ -165,7 +164,6 @@ def _w4a8_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *, bits: int,
     scale = s_ref[...].astype(jnp.float32)        # (bk // group, bn)
     zp = z_ref[...].astype(jnp.float32)
     xq32 = x_q.astype(jnp.int32)
-    part = jnp.zeros_like(acc_ref)
     for gi in range(bk // group):                 # static unroll over groups
         sl = slice(gi * group, (gi + 1) * group)
         dot = jax.lax.dot_general(
@@ -173,14 +171,24 @@ def _w4a8_kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *, bits: int,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
         rsum = jnp.sum(xq32[:, sl], axis=1, keepdims=True)
-        part += scale[gi][None, :] * (
+        # groups accumulate in K order into one f32 sum, as in the oracle
+        acc_ref[...] += scale[gi][None, :] * (
             dot.astype(jnp.float32)
             + rsum.astype(jnp.float32) * (off - zp[gi])[None, :])
-    acc_ref[...] += part * a_scale
 
     @pl.when(ki == n_k - 1)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * a_scale).astype(o_ref.dtype)
+
+
+def act_scale(x: jax.Array, a_bits: int) -> jax.Array:
+    """Per-token dynamic symmetric activation scale over the whole row:
+    x (M, K) -> (M, 1) f32.  Shared by the kernel wrapper and the oracle,
+    so both quantize on the same grid however K is tiled."""
+    qmax = 2.0 ** (a_bits - 1) - 1.0
+    xf = x.astype(jnp.float32)
+    return jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True),
+                       1e-8) / qmax
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group_size", "a_bits",
@@ -193,13 +201,15 @@ def w4a8_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
 
     x (M, K) float; packed (K // 8 * bits, N) uint8 codes; scale/zp
     (K // group, N) float32 per-group affine grid (the QTensor fields).
+    Returns (M, N) float32: the caller rounds to its activation dtype
+    with an XLA convert, the same op its oracle program uses.
     Despite the name this is the general w{2,4,8}a{4,8} kernel — codes are
     widened to int8 MXU lanes in-kernel whatever ``bits`` is.
 
-    With ``bk >= K`` the per-token activation scale spans the whole row and
-    the result is bit-identical to ``ref.quant_matmul_ref``; for ``bk < K``
-    each K-slab gets its own activation scale (error <= the whole-row
-    scheme, same argument as ``w8a8_matmul``).
+    The per-token activation scale spans the whole row whatever ``bk``
+    (:func:`act_scale`, computed once before the kernel and streamed as a
+    (bm, 1) block), so interpret mode is bit-identical to
+    ``ref.quant_matmul_ref``.
     """
     m, k = x.shape
     n = packed.shape[-1]
@@ -215,12 +225,13 @@ def w4a8_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
         grid=(m // bm, n // bn, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, ki: (i, ki)),
+            pl.BlockSpec((bm, 1), lambda i, j, ki: (i, 0)),
             pl.BlockSpec((rows_per_bk, bn), lambda i, j, ki: (ki, j)),
             pl.BlockSpec((sg, bn), lambda i, j, ki: (ki, j)),
             pl.BlockSpec((sg, bn), lambda i, j, ki: (ki, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, ki: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, packed, scale, zp)
+    )(x, act_scale(x, a_bits), packed, scale, zp)

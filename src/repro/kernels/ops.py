@@ -15,7 +15,7 @@ from typing import Literal, Optional
 import jax
 import jax.numpy as jnp
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import sharding as _sharding
 from repro.core.qtensor import QTensor
@@ -60,6 +60,47 @@ def _tp_mesh(hq: int, hkv: int, b: int):
     return mesh, dp
 
 
+def _tp_columns(m: int, n: int):
+    """(mesh, dp_axis) when the bound mesh splits a packed matmul's output
+    columns over "model"; None for unsharded dispatch.  A Mosaic kernel
+    cannot be partitioned by GSPMD, so under a mesh every packed matmul
+    runs under shard_map as a column-parallel call: x whole along K on
+    every shard, the weight's codes/scales/zero-points sliced along N
+    (a local slice where the weight is stored replicated), each output
+    column computed in full on one shard — the same math as one device."""
+    mesh = _sharding.current_mesh()
+    if mesh is None or "model" not in mesh.axis_names:
+        return None
+    mm = mesh.shape["model"]
+    if mm <= 1 or n % mm:
+        return None
+    dp = ("data" if "data" in mesh.axis_names and mesh.shape["data"] > 1
+          and m % mesh.shape["data"] == 0 else None)
+    return mesh, dp
+
+
+def _tp_matmul(fn, x2, packed, scale, zp):
+    """Run ``fn(x2, packed, scale, zp)`` column-parallel when a mesh is
+    bound (see :func:`_tp_columns`), else directly."""
+    tp = _tp_columns(x2.shape[0], packed.shape[-1])
+    if tp is None:
+        return fn(x2, packed, scale, zp)
+    mesh, dp = tp
+    col = P(None, "model")
+    out = _tp_call(
+        mesh, lambda a: fn(a["x"], a["packed"], a["scale"], a["zp"]),
+        {"x": x2, "packed": packed, "scale": scale, "zp": zp},
+        {"x": P(dp, None), "packed": col, "scale": col, "zp": col},
+        P(dp, "model"))
+    # gather whole rows back: every op between the kernels (norms, RoPE,
+    # residual adds, KV quantize-on-write) then reduces over the same
+    # values in the same order as on one device — a GSPMD-partitioned
+    # norm would sum in another order, and one flipped bf16/int4 rounding
+    # is enough to change a greedy token
+    return jax.lax.with_sharding_constraint(
+        out, NamedSharding(mesh, P(dp, None)))
+
+
 def _kv_entry_specs(k_scale, dp, paged: bool):
     """PartitionSpecs for cache entries: head dim (index 2) over "model";
     linear entries batch-split over ``dp``, pool entries replicated over
@@ -74,12 +115,23 @@ def _kv_entry_specs(k_scale, dp, paged: bool):
 def _tp_call(mesh, fn, args: dict, specs: dict, out_spec: P):
     """Run ``fn`` over dict-packed args under shard_map (dropping entries
     that are None so the arg/spec pytrees stay congruent)."""
-    from jax.experimental.shard_map import shard_map
     live = {n: a for n, a in args.items() if a is not None}
     live_specs = {n: specs[n] for n in live}
-    wrapped = shard_map(fn, mesh=mesh, in_specs=(live_specs,),
-                        out_specs=out_spec, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(live_specs,),
+                            out_specs=out_spec, check_vma=False)
     return wrapped(live)
+
+
+def _materialize(x):
+    """``x`` as a stored array: XLA may not fuse its producer into its
+    consumer.  A kernel reads and writes whole arrays in their storage
+    dtype (bf16 activations stay rounded to bf16 in HBM); without this
+    boundary XLA may keep an activation in f32 across a
+    ``.astype(bfloat16)`` that it fuses into the oracle's quantizer or
+    into ``act_scale``, and the two programs would then quantize other
+    values.  Every op below passes its activations in and out through
+    here, in every mode."""
+    return jax.lax.optimization_barrier(x)
 
 
 def _backend() -> str:
@@ -142,22 +194,26 @@ def dequant_matmul(x, packed, scale=None, zp=None, *,
                         "packed/scale/zp/bits/group_size")
     lead = x.shape[:-1]
     k = x.shape[-1]
-    x2 = x.reshape(-1, k)
-    m = x2.shape[0]
     impl = _resolve(mode)
-    if impl == "ref" or bits == 3:   # 3-bit: storage-only format, ref math
-        out = ref.dequant_matmul_ref(x2, packed, scale, zp, bits=bits,
-                                     group_size=group_size)
-    else:
-        bm = blocks.pop("bm", dq.DEFAULT_BM)
+
+    def run(x2, packed, scale, zp):
+        if impl == "ref" or bits == 3:  # 3-bit: storage-only format, ref math
+            return ref.dequant_matmul_ref(x2, packed, scale, zp, bits=bits,
+                                          group_size=group_size)
+        m = x2.shape[0]
+        bm = blocks.get("bm", dq.DEFAULT_BM)
         m_pad, bm = _pick_bm(m, bm)
-        blocks = _clamp_blocks(k, packed.shape[-1], blocks, group_size)
+        blk = _clamp_blocks(k, packed.shape[-1],
+                            {n: v for n, v in blocks.items() if n != "bm"},
+                            group_size)
         x_p = jnp.pad(x2, ((0, m_pad - m), (0, 0))) if m_pad != m else x2
         out = dq.dequant_matmul(x_p, packed, scale, zp, bits=bits,
                                 group_size=group_size, bm=bm,
-                                interpret=(impl == "interpret"), **blocks)
-        out = out[:m]
-    return out.reshape(*lead, out.shape[-1])
+                                interpret=(impl == "interpret"), **blk)
+        return out[:m]
+
+    out = _tp_matmul(run, _materialize(x.reshape(-1, k)), packed, scale, zp)
+    return _materialize(out).reshape(*lead, out.shape[-1])
 
 
 def quant_matmul(x, qt: QTensor, *, a_bits: int = 8, mode: Mode = "auto",
@@ -185,33 +241,40 @@ def quant_matmul(x, qt: QTensor, *, a_bits: int = 8, mode: Mode = "auto",
                          "lanes) or >= 16 (fp activations)")
     lead = x.shape[:-1]
     k = x.shape[-1]
-    x2 = x.reshape(-1, k)
-    m = x2.shape[0]
     impl = _resolve(mode)
-    if impl == "ref" or qt.bits == 3:
-        out = ref.quant_matmul_ref(x2, qt.packed, qt.scale, qt.zp,
-                                   bits=qt.bits, group_size=qt.group_size,
-                                   a_bits=a_bits)
-    else:
-        bm = blocks.pop("bm", i8.DEFAULT_BM)
-        m_pad, bm = _pick_bm(m, bm)
-        n = qt.packed.shape[-1]
-        g = qt.group_size or k          # 0 = per-channel: one K-wide group
-        bk = blocks.pop("bk", i8.DEFAULT_BK)
-        bn = blocks.pop("bn", i8.DEFAULT_BN)
+    bits = qt.bits
+    g = qt.group_size or k          # 0 = per-channel: one K-wide group
+
+    def run(x2, packed, scale, zp):
+        # kernel and oracle both return f32; the one rounding to the
+        # activation dtype is XLA's convert, the same op in every program
+        if impl == "ref" or bits == 3:
+            return ref.quant_matmul_ref(x2, packed, scale, zp, bits=bits,
+                                        group_size=qt.group_size,
+                                        a_bits=a_bits,
+                                        out_dtype=jnp.float32
+                                        ).astype(x2.dtype)
+        m = x2.shape[0]
+        m_pad, bm = _pick_bm(m, blocks.get("bm", i8.DEFAULT_BM))
+        n = packed.shape[-1]
+        bk = blocks.get("bk", i8.DEFAULT_BK)
+        bn = blocks.get("bn", i8.DEFAULT_BN)
         # NOT _clamp_blocks: this path requires the strict bk % g == 0 (the
         # kernel's scale/zp BlockSpec steps one group-slab per K block; the
         # weight-only kernel also tolerates g % bk == 0, this one does not)
         if k % bk != 0 or bk % g != 0:
-            bk = k   # single K block: fused act-quant matches per-token ref
+            bk = k   # single K block
         if n % bn != 0:
             bn = n
         x_p = jnp.pad(x2, ((0, m_pad - m), (0, 0))) if m_pad != m else x2
-        out = i8.w4a8_matmul(x_p, qt.packed, qt.scale, qt.zp, bits=qt.bits,
+        out = i8.w4a8_matmul(x_p, packed, scale, zp, bits=bits,
                              group_size=g, a_bits=a_bits, bm=bm, bn=bn,
                              bk=bk, interpret=(impl == "interpret"))
-        out = out[:m]
-    return out.reshape(*lead, out.shape[-1])
+        return out[:m].astype(x2.dtype)
+
+    out = _tp_matmul(run, _materialize(x.reshape(-1, k)), qt.packed,
+                     qt.scale, qt.zp)
+    return _materialize(out).reshape(*lead, out.shape[-1])
 
 
 def w8a8_matmul(x, w_q, w_scale, *, mode: Mode = "auto", **blocks):
@@ -281,6 +344,12 @@ def flash_decode(q, kv, cur_len, *, scale=None, block_kv: Optional[int] = None,
     # the oracle is the test contract, the fallback is the fast portable path
     impl = ("pallas" if _backend() == "tpu" else "xla") if mode == "auto" \
         else mode
+    q = _materialize(q)
+    dt = q.dtype
+    if impl != "xla":
+        # the kernels and their oracles take and return f32 rows; the one
+        # rounding to the activation dtype is XLA's, in every program
+        q = q.astype(jnp.float32)
     tp = _tp_mesh(hq, k.shape[2], b)
     if tp is not None:
         mesh, dp = tp
@@ -296,9 +365,11 @@ def flash_decode(q, kv, cur_len, *, scale=None, block_kv: Optional[int] = None,
         fn = lambda a: _flash_decode_dispatch(
             a["q"], a["k"], a["v"], a.get("k_scale"), a.get("v_scale"),
             a["cur_len"], a.get("page_table"), scale, impl, block_kv)
-        return _tp_call(mesh, fn, args, specs, P(dp, None, "model", None))
-    return _flash_decode_dispatch(q, k, v, k_scale, v_scale, cur_len,
-                                  page_table, scale, impl, block_kv)
+        return _materialize(_tp_call(
+            mesh, fn, args, specs, P(dp, None, "model", None)).astype(dt))
+    return _materialize(_flash_decode_dispatch(
+        q, k, v, k_scale, v_scale, cur_len, page_table, scale, impl,
+        block_kv).astype(dt))
 
 
 def _flash_decode_dispatch(q, k, v, k_scale, v_scale, cur_len, page_table,
@@ -442,6 +513,12 @@ def flash_prefill(q, kv, offset, chunk_len, *, scale=None,
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
     impl = ("pallas" if _backend() == "tpu" else "xla") if mode == "auto" \
         else mode
+    q = _materialize(q)
+    dt = q.dtype
+    if impl != "xla":
+        # the kernels and their oracles take and return f32 rows; the one
+        # rounding to the activation dtype is XLA's, in every program
+        q = q.astype(jnp.float32)
     tp = _tp_mesh(hq, k.shape[2], b)
     if tp is not None:
         mesh, dp = tp
@@ -459,10 +536,11 @@ def flash_prefill(q, kv, offset, chunk_len, *, scale=None,
             a["q"], a["k"], a["v"], a.get("k_scale"), a.get("v_scale"),
             a["offset"], a["chunk_len"], a.get("page_table"), scale, impl,
             block_kv)
-        return _tp_call(mesh, fn, args, specs, P(dp, None, "model", None))
-    return _flash_prefill_dispatch(q, k, v, k_scale, v_scale, offset,
-                                   chunk_len, page_table, scale, impl,
-                                   block_kv)
+        return _materialize(_tp_call(
+            mesh, fn, args, specs, P(dp, None, "model", None)).astype(dt))
+    return _materialize(_flash_prefill_dispatch(
+        q, k, v, k_scale, v_scale, offset, chunk_len, page_table, scale,
+        impl, block_kv).astype(dt))
 
 
 def _flash_prefill_dispatch(q, k, v, k_scale, v_scale, offset, chunk_len,

@@ -8,7 +8,7 @@ group axis is K (input features), matching the dequant-matmul layout.
     grid (K/g, N/bn)       one program per (group, N-block)
     w block  (g, bn)       VMEM in
     packed   (g//8*bits, bn) VMEM out
-    scale/zp (1, bn)       VMEM out
+    scale/zp (1, 1, bn)    VMEM out (rows of a (K/g, 1, N) array)
 
 kv4 (MX-style microscaling, ``kv_bits=4``): :func:`kv4_quantize` packs K/V
 vectors into two int4 codes per byte along D with ONE bf16 scale per block
@@ -77,10 +77,10 @@ def kv4_dequant(packed: jax.Array, scales: jax.Array) -> jax.Array:
 
 
 def _pack_block(codes: jax.Array, bits: int) -> jax.Array:
-    """(g, bn) uint8 codes -> (g//8*bits, bn) uint8 bytes (little-endian
+    """(g, bn) uint32 codes -> (g//8*bits, bn) uint8 bytes (little-endian
     8-value groups; inverse of dequant_matmul._unpack_block)."""
     g, bn = codes.shape
-    cu = codes.reshape(g // 8, 8, bn).astype(jnp.uint32)
+    cu = codes.reshape(g // 8, 8, bn)
     out = []
     for byte_idx in range(bits):
         acc = jnp.zeros((g // 8, bn), jnp.uint32)
@@ -103,11 +103,12 @@ def _kernel(w_ref, p_ref, s_ref, z_ref, *, bits: int):
     wmin = jnp.min(wf, axis=0, keepdims=True)
     scale = jnp.maximum(wmax - wmin, 1e-8) / (2 ** bits - 1)
     zp = jnp.round(-wmin / scale)
+    # f32 -> int32 -> uint32: Mosaic casts floats to signed ints only
     codes = jnp.clip(jnp.round(wf / scale) + zp, 0, 2 ** bits - 1
-                     ).astype(jnp.uint8)
+                     ).astype(jnp.int32).astype(jnp.uint32)
     p_ref[...] = _pack_block(codes, bits)
-    s_ref[...] = scale
-    z_ref[...] = zp
+    s_ref[0] = scale
+    z_ref[0] = zp
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group_size", "bn",
@@ -119,20 +120,23 @@ def quantize_pack(w: jax.Array, *, bits: int, group_size: int,
     g = group_size if group_size else k
     assert k % g == 0 and g % 8 == 0 and n % bn == 0, (k, g, n, bn)
     rows = g // 8 * bits
-
-    return pl.pallas_call(
+    # scale/zp rows are written as (K//g, 1, N): a (1, bn) block over a
+    # (K//g, N) array would put a 1 in the second-minor place, which the
+    # TPU lowering refuses; the unit axis is dropped after the call
+    packed, scale, zp = pl.pallas_call(
         functools.partial(_kernel, bits=bits),
         grid=(k // g, n // bn),
         in_specs=[pl.BlockSpec((g, bn), lambda gi, j: (gi, j))],
         out_specs=[
             pl.BlockSpec((rows, bn), lambda gi, j: (gi, j)),
-            pl.BlockSpec((1, bn), lambda gi, j: (gi, j)),
-            pl.BlockSpec((1, bn), lambda gi, j: (gi, j)),
+            pl.BlockSpec((1, 1, bn), lambda gi, j: (gi, 0, j)),
+            pl.BlockSpec((1, 1, bn), lambda gi, j: (gi, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k // 8 * bits, n), jnp.uint8),
-            jax.ShapeDtypeStruct((k // g, n), jnp.float32),
-            jax.ShapeDtypeStruct((k // g, n), jnp.float32),
+            jax.ShapeDtypeStruct((k // g, 1, n), jnp.float32),
+            jax.ShapeDtypeStruct((k // g, 1, n), jnp.float32),
         ],
         interpret=interpret,
     )(w)
+    return packed, scale.reshape(k // g, n), zp.reshape(k // g, n)

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.packing import unpack
-from repro.kernels.quantize_pack import kv4_dequant
+from repro.kernels.flash_decode import NEG_INF, attend_tile, kv_format
 
 
 def dequant_matmul_ref(x: jax.Array, packed: jax.Array, scale: jax.Array,
@@ -71,12 +71,12 @@ def quant_matmul_ref(x: jax.Array, packed: jax.Array, scale: jax.Array,
     order exactly, so ``w4a8_matmul`` in interpret mode with ``bk >= K``
     (one K block == whole-row activation scale) is bit-identical to this.
     """
+    from repro.kernels.int8_matmul import act_scale
     m, k = x.shape
     n = packed.shape[-1]
     xf = x.astype(jnp.float32)
     qmax = 2.0 ** (a_bits - 1) - 1.0
-    bound = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True), 1e-8)
-    a_scale = bound / qmax
+    a_scale = act_scale(x, a_bits)
     x_q = jnp.clip(jnp.round(xf / a_scale), -qmax - 1.0, qmax
                    ).astype(jnp.int8)
 
@@ -98,6 +98,87 @@ def quant_matmul_ref(x: jax.Array, packed: jax.Array, scale: jax.Array,
     return out.astype(out_dtype or x.dtype)
 
 
+def _flash_grid(q, seq, n_tiles, fetch, live, valid, *, scale, kv_bits):
+    """Walk the flash kernels' grid in plain jnp, one sequence at a time:
+    KV tile ``t`` inner, every head per tile — the same
+    :func:`repro.kernels.flash_decode.attend_tile` per (tile, head) in the
+    same order, with ``lax.cond`` standing in for the kernel's predicated
+    tiles.  Walking one sequence per loop iteration (not a batch at once)
+    is what keeps interpret mode BIT-identical to this under jit: XLA
+    fuses, and so rounds, a batched loop body differently.
+
+    ``q`` (B, Hkv, R, D) f32; ``seq`` a pytree of per-sequence arrays
+    (leading dim B) handed to ``fetch(a, t)`` -> (k, v, ks, vs) tiles
+    (block_kv, Hkv, ...), ``live(a, t)`` bool scalar and ``valid(a, t)``
+    mask broadcastable to (R, block_kv).  Returns acc / l
+    (B, Hkv, R, D) f32.  Only one KV tile is dequantized at a time —
+    never the full cache.
+    """
+    _, hkv, r, d = q.shape
+
+    def one(xs):
+        qb, a = xs
+
+        def tile(t, st):
+            def update(st):
+                k, v, ks, vs = fetch(a, t)
+                ok = valid(a, t)
+
+                def head(h, st):
+                    # dynamic head index: one head's math per loop body
+                    # (unrolling Hkv heads, as the kernel does, makes the
+                    # oracle slow to compile at real head counts)
+                    sel = lambda x: jax.lax.dynamic_index_in_dim(
+                        x, h, 1, keepdims=False)
+                    if kv_bits == 8:
+                        hs = [jax.lax.dynamic_slice_in_dim(x, h, 1, 1)
+                              for x in (ks, vs)]
+                    elif kv_bits == 4:
+                        hs = [sel(ks), sel(vs)]
+                    else:
+                        hs = [None, None]
+                    new = attend_tile(
+                        jax.lax.dynamic_index_in_dim(qb, h, 0, False),
+                        sel(k), sel(v), *hs, ok,
+                        *(jax.lax.dynamic_index_in_dim(x, h, 0, False)
+                          for x in st), scale=scale, kv_bits=kv_bits)
+                    return tuple(x.at[h].set(y) for x, y in zip(st, new))
+
+                return jax.lax.fori_loop(0, hkv, head, st)
+            return jax.lax.cond(live(a, t), update, lambda st: st, st)
+
+        init = (jnp.full((hkv, r, 1), NEG_INF, jnp.float32),
+                jnp.zeros((hkv, r, 1), jnp.float32),
+                jnp.zeros((hkv, r, d), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, n_tiles, tile, init)
+        return acc / jnp.maximum(l, 1e-30)
+
+    return jax.lax.map(one, (q, seq))
+
+
+def _linear_fetch(block_kv):
+    """Tiles of one sequence's linear cache slab ``a["kv"]``."""
+    def fetch(a, t):
+        tile = lambda x: None if x is None else jax.lax.dynamic_slice_in_dim(
+            x, t * block_kv, block_kv, axis=0)
+        return tuple(tile(x) for x in a["kv"])
+    return fetch
+
+
+def _paged_fetch(k_pool, v_pool, k_scale, v_scale):
+    """Tile ``t`` gathers pool page ``a["pt"][t]``, clamped like the
+    kernels' index maps to the last page holding ``a["total"]`` tokens
+    (only live tiles are read)."""
+    ps = k_pool.shape[1]
+
+    def fetch(a, t):
+        last = jnp.maximum((a["total"] + ps - 1) // ps - 1, 0)
+        page = jnp.maximum(a["pt"][jnp.minimum(t, last)], 0)
+        get = lambda x: None if x is None else x[page]
+        return get(k_pool), get(v_pool), get(k_scale), get(v_scale)
+    return fetch
+
+
 def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                      cur_len: jax.Array, k_scale=None, v_scale=None, *,
                      scale=None, block_kv: int = 128,
@@ -107,55 +188,24 @@ def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     q (B, Hkv, G, D); k/v (B, S, Hkv, D) — kv8 int8 codes when 3D
     ``k_scale`` / ``v_scale`` (B, S, Hkv) f32 are given, kv4 packed nibbles
     (B, S, Hkv, D//2) when the scales are 4D (B, S, Hkv, D//32) bf16 block
-    grids, fp otherwise; cur_len (B,) valid
-    positions. Mirrors ``flash_decode.flash_decode`` op-for-op: the same
-    per-tile dequant → scores → mask → online-softmax update sequence, with
-    masked (``jnp.where``) state updates standing in for the kernel's
-    predicated tiles — so the kernel in interpret mode is BIT-IDENTICAL to
-    this under jit. Positions ``>= cur_len[b]`` are masked; a zero-length
-    row returns zeros. Unlike the kernel this materializes only one
-    (B, block_kv, Hkv, D) fp tile at a time — never the full cache.
+    grids, fp otherwise; cur_len (B,) valid positions.  Walks
+    ``flash_decode.flash_decode``'s grid op-for-op (:func:`_flash_grid`),
+    so the kernel in interpret mode is BIT-IDENTICAL to this under jit.
+    Positions ``>= cur_len[b]`` are masked; a zero-length row returns
+    zeros.
     """
-    bsz, hkv, g, d = q.shape
+    d = q.shape[-1]
     s = k.shape[1]
     assert s % block_kv == 0, (s, block_kv)
-    n_tiles = s // block_kv
-    packed = k_scale is not None and k_scale.ndim == k.ndim
-    scale = scale if scale is not None else d ** -0.5
-    cur = cur_len.astype(jnp.int32)[:, None, None, None]
-    qf = q.astype(jnp.float32)
-    m = jnp.full((bsz, hkv, g, 1), -1e30, jnp.float32)
-    l = jnp.zeros((bsz, hkv, g, 1), jnp.float32)
-    acc = jnp.zeros((bsz, hkv, g, d), jnp.float32)
-    for t in range(n_tiles):
-        sl = slice(t * block_kv, (t + 1) * block_kv)
-        if packed:
-            # SAME kv4_dequant the kernel body runs — elementwise, so the
-            # extra batch/head ranks change nothing bit-wise
-            kt = kv4_dequant(k[:, sl], k_scale[:, sl])
-            vt = kv4_dequant(v[:, sl], v_scale[:, sl])
-        else:
-            kt = k[:, sl].astype(jnp.float32)
-            vt = v[:, sl].astype(jnp.float32)
-            if k_scale is not None:
-                kt = kt * k_scale[:, sl][..., None]
-                vt = vt * v_scale[:, sl][..., None]
-        sc = jnp.einsum("bhgd,bkhd->bhgk", qf, kt,
-                        preferred_element_type=jnp.float32) * scale
-        pos = t * block_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_kv), 1)
-        sc = jnp.where(pos[None, None] < cur, sc, -1e30)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.einsum(
-            "bhgk,bkhd->bhgd", p, vt, preferred_element_type=jnp.float32)
-        live = t * block_kv < cur
-        m = jnp.where(live, m_new, m)
-        l = jnp.where(live, l_new, l)
-        acc = jnp.where(live, acc_new, acc)
-    out = acc / jnp.maximum(l, 1e-30)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block_kv), 1)
+    out = _flash_grid(
+        q.astype(jnp.float32),
+        {"kv": (k, v, k_scale, v_scale), "cur": cur_len.astype(jnp.int32)},
+        s // block_kv, _linear_fetch(block_kv),
+        lambda a, t: t * block_kv < a["cur"],
+        lambda a, t: t * block_kv + iota < a["cur"],
+        scale=scale if scale is not None else d ** -0.5,
+        kv_bits=kv_format(k, k_scale, v_scale))
     return out.astype(out_dtype or q.dtype)
 
 
@@ -172,54 +222,41 @@ def flash_decode_paged_ref(q: jax.Array, k_pool: jax.Array,
     (num_pages, page_size, Hkv, D//32) bf16, fp otherwise;
     ``page_table`` (B, max_pages_per_seq) int32 (−1 = unallocated);
     ``cur_len`` (B,) valid positions.  One tile == one page: tile ``t``
-    gathers pool page ``page_table[:, t]`` and runs the exact per-tile
-    dequant → scores → mask → online-softmax sequence of
-    ``flash_decode.flash_decode_paged`` with masked (``jnp.where``) state
-    updates standing in for predication — interpret mode is BIT-IDENTICAL
-    to this under jit.  Tiles at or past ``ceil(cur_len / page_size)`` may
-    gather stale or clamped pages; their state updates are discarded, as
-    the kernel's predication discards theirs.  A zero-length row returns
-    zeros.  Like the linear oracle, only one (B, page_size, Hkv, D) fp tile
-    exists at a time — never a gathered full cache.
+    gathers pool page ``page_table[b, t]`` and runs the grid of
+    ``flash_decode.flash_decode_paged`` op-for-op — interpret mode is
+    BIT-IDENTICAL to this under jit.  A zero-length row returns zeros.
     """
-    bsz, hkv, g, d = q.shape
+    d = q.shape[-1]
     ps = k_pool.shape[1]
-    n_tiles = page_table.shape[1]
-    packed = k_scale is not None and k_scale.ndim == k_pool.ndim
-    scale = scale if scale is not None else d ** -0.5
-    cur = cur_len.astype(jnp.int32)[:, None, None, None]
-    qf = q.astype(jnp.float32)
-    m = jnp.full((bsz, hkv, g, 1), -1e30, jnp.float32)
-    l = jnp.zeros((bsz, hkv, g, 1), jnp.float32)
-    acc = jnp.zeros((bsz, hkv, g, d), jnp.float32)
-    for t in range(n_tiles):
-        pages = jnp.maximum(page_table[:, t], 0)          # (B,)
-        if packed:
-            # SAME kv4_dequant the kernel body runs, on the gathered pages
-            kt = kv4_dequant(k_pool[pages], k_scale[pages])
-            vt = kv4_dequant(v_pool[pages], v_scale[pages])
-        else:
-            kt = k_pool[pages].astype(jnp.float32)        # (B, ps, Hkv, D)
-            vt = v_pool[pages].astype(jnp.float32)
-            if k_scale is not None:
-                kt = kt * k_scale[pages][..., None]
-                vt = vt * v_scale[pages][..., None]
-        sc = jnp.einsum("bhgd,bkhd->bhgk", qf, kt,
-                        preferred_element_type=jnp.float32) * scale
-        pos = t * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        sc = jnp.where(pos[None, None] < cur, sc, -1e30)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.einsum(
-            "bhgk,bkhd->bhgd", p, vt, preferred_element_type=jnp.float32)
-        live = t * ps < cur
-        m = jnp.where(live, m_new, m)
-        l = jnp.where(live, l_new, l)
-        acc = jnp.where(live, acc_new, acc)
-    out = acc / jnp.maximum(l, 1e-30)
+    cur = cur_len.astype(jnp.int32)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+    out = _flash_grid(
+        q.astype(jnp.float32),
+        {"pt": page_table.astype(jnp.int32), "total": cur},
+        page_table.shape[1], _paged_fetch(k_pool, v_pool, k_scale, v_scale),
+        lambda a, t: t * ps < a["total"],
+        lambda a, t: t * ps + iota < a["total"],
+        scale=scale if scale is not None else d ** -0.5,
+        kv_bits=kv_format(k_pool, k_scale, v_scale))
     return out.astype(out_dtype or q.dtype)
+
+
+def _prefill_masks(offset, chunk_len, c, g, tile):
+    """(per-sequence args, live, valid, row_live) of the chunked-prefill
+    grid: position ``p`` is valid for row ``r`` (chunk token ``r // G``)
+    iff ``p <= offset + r // G`` and ``r // G < chunk_len``;
+    ``chunk_len == 0`` sequences visit no tiles."""
+    off = offset.astype(jnp.int32)
+    cl = chunk_len.astype(jnp.int32)
+    seq = {"off": off, "cl": cl, "total": jnp.where(cl > 0, off + cl, 0)}
+    row_tok = jax.lax.broadcasted_iota(jnp.int32, (c * g, tile), 0) // g
+    kv_iota = jax.lax.broadcasted_iota(jnp.int32, (c * g, tile), 1)
+    live = lambda a, t: t * tile < a["total"]
+    valid = lambda a, t: ((t * tile + kv_iota <= a["off"] + row_tok)
+                          & (row_tok < a["cl"]))
+    row_live = (jax.lax.broadcasted_iota(jnp.int32, (c * g, 1), 0) // g
+                < cl[:, None, None, None])              # (B, 1, R, 1)
+    return seq, live, valid, row_live
 
 
 def flash_prefill_ref(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -233,66 +270,24 @@ def flash_prefill_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     codes when 3D ``k_scale``/``v_scale`` (B, S, Hkv) f32 are given, kv4
     packed nibbles (B, S, Hkv, D//2) when the scales are 4D
     (B, S, Hkv, D//32) bf16 block grids, fp otherwise —
-    **as stored**, with the chunk's own K/V already written.  Mirrors
-    ``flash_prefill.flash_prefill`` op-for-op: the same per-tile dequant →
-    scores → causal/pad mask → online-softmax update sequence, with masked
-    (``jnp.where``) state updates standing in for the kernel's predicated
-    tiles — so the kernel in interpret mode is BIT-IDENTICAL to this under
-    jit.  Position ``p`` is valid for chunk row ``i`` iff
-    ``p <= offset[b] + i`` and ``i < chunk_len[b]``; pad rows return zeros.
-    Like the decode oracle this materializes only one (B, block_kv, Hkv, D)
-    fp tile at a time — never the full cache.
+    **as stored**, with the chunk's own K/V already written.  Walks
+    ``flash_prefill.flash_prefill``'s grid op-for-op, so the kernel in
+    interpret mode is BIT-IDENTICAL to this under jit.  Position ``p`` is
+    valid for chunk row ``i`` iff ``p <= offset[b] + i`` and
+    ``i < chunk_len[b]``; pad rows return zeros.
     """
     bsz, hkv, c, g, d = q.shape
     s = k.shape[1]
     assert s % block_kv == 0, (s, block_kv)
-    n_tiles = s // block_kv
-    packed = k_scale is not None and k_scale.ndim == k.ndim
-    r = c * g
-    scale = scale if scale is not None else d ** -0.5
-    off = offset.astype(jnp.int32)[:, None, None, None]
-    cl = chunk_len.astype(jnp.int32)[:, None, None, None]
-    # chunk_len == 0 sequences visit no tiles (mirrors the kernel's grid
-    # predicate): their state stays at init and the row mask zeroes them
-    total = jnp.where(cl > 0, off + cl, 0)
-    qf = q.astype(jnp.float32).reshape(bsz, hkv, r, d)
-    row_tok = (jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
-               // g)[None, None]                               # (1, 1, r, 1)
-    m = jnp.full((bsz, hkv, r, 1), -1e30, jnp.float32)
-    l = jnp.zeros((bsz, hkv, r, 1), jnp.float32)
-    acc = jnp.zeros((bsz, hkv, r, d), jnp.float32)
-    for t in range(n_tiles):
-        sl = slice(t * block_kv, (t + 1) * block_kv)
-        if packed:
-            # SAME kv4_dequant the kernel body runs
-            kt = kv4_dequant(k[:, sl], k_scale[:, sl])
-            vt = kv4_dequant(v[:, sl], v_scale[:, sl])
-        else:
-            kt = k[:, sl].astype(jnp.float32)
-            vt = v[:, sl].astype(jnp.float32)
-            if k_scale is not None:
-                kt = kt * k_scale[:, sl][..., None]
-                vt = vt * v_scale[:, sl][..., None]
-        sc = jnp.einsum("bhrd,bkhd->bhrk", qf, kt,
-                        preferred_element_type=jnp.float32) * scale
-        kv_pos = (t * block_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_kv), 1))[None, None]          # (1, 1, 1, bk)
-        valid = (kv_pos <= off + row_tok) & (row_tok < cl)
-        sc = jnp.where(valid, sc, -1e30)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.einsum(
-            "bhrk,bkhd->bhrd", p, vt, preferred_element_type=jnp.float32)
-        live = t * block_kv < total
-        m = jnp.where(live, m_new, m)
-        l = jnp.where(live, l_new, l)
-        acc = jnp.where(live, acc_new, acc)
-    out = acc / jnp.maximum(l, 1e-30)
-    # pad rows are fully masked yet accumulate exp(0) junk — zero them,
-    # exactly as the kernel's final-tile epilogue does
-    out = jnp.where(row_tok < cl, out, 0.0)
+    seq, live, valid, row_live = _prefill_masks(offset, chunk_len, c, g,
+                                                block_kv)
+    seq["kv"] = (k, v, k_scale, v_scale)
+    out = _flash_grid(
+        q.astype(jnp.float32).reshape(bsz, hkv, c * g, d), seq,
+        s // block_kv, _linear_fetch(block_kv), live, valid,
+        scale=scale if scale is not None else d ** -0.5,
+        kv_bits=kv_format(k, k_scale, v_scale))
+    out = jnp.where(row_live, out, 0.0)
     return out.reshape(bsz, hkv, c, g, d).astype(out_dtype or q.dtype)
 
 
@@ -304,61 +299,22 @@ def flash_prefill_paged_ref(q: jax.Array, k_pool: jax.Array,
     """Tile-mirroring oracle for the paged chunked-prefill kernel.
 
     Pools/page-table layout as in :func:`flash_decode_paged_ref`; one tile
-    == one page, tile ``t`` gathers pool page ``page_table[:, t]`` and runs
-    the exact per-tile sequence of ``flash_prefill.flash_prefill_paged``
-    with masked (``jnp.where``) state updates standing in for predication —
-    interpret mode is BIT-IDENTICAL to this under jit.  Tiles at or past
-    ``ceil((offset + chunk_len) / page_size)`` may gather stale or clamped
-    pages; their state updates are discarded, as the kernel's predication
-    discards theirs.  Pad rows return zeros.
+    == one page, tile ``t`` gathers pool page ``page_table[b, t]`` and the
+    grid of ``flash_prefill.flash_prefill_paged`` runs op-for-op —
+    interpret mode is BIT-IDENTICAL to this under jit.  Pad rows return
+    zeros.
     """
     bsz, hkv, c, g, d = q.shape
     ps = k_pool.shape[1]
-    n_tiles = page_table.shape[1]
-    packed = k_scale is not None and k_scale.ndim == k_pool.ndim
-    r = c * g
-    scale = scale if scale is not None else d ** -0.5
-    off = offset.astype(jnp.int32)[:, None, None, None]
-    cl = chunk_len.astype(jnp.int32)[:, None, None, None]
-    # chunk_len == 0 sequences visit no tiles (mirrors the kernel's grid
-    # predicate): their state stays at init and the row mask zeroes them
-    total = jnp.where(cl > 0, off + cl, 0)
-    qf = q.astype(jnp.float32).reshape(bsz, hkv, r, d)
-    row_tok = (jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
-               // g)[None, None]                               # (1, 1, r, 1)
-    m = jnp.full((bsz, hkv, r, 1), -1e30, jnp.float32)
-    l = jnp.zeros((bsz, hkv, r, 1), jnp.float32)
-    acc = jnp.zeros((bsz, hkv, r, d), jnp.float32)
-    for t in range(n_tiles):
-        pages = jnp.maximum(page_table[:, t], 0)          # (B,)
-        if packed:
-            # SAME kv4_dequant the kernel body runs, on the gathered pages
-            kt = kv4_dequant(k_pool[pages], k_scale[pages])
-            vt = kv4_dequant(v_pool[pages], v_scale[pages])
-        else:
-            kt = k_pool[pages].astype(jnp.float32)        # (B, ps, Hkv, D)
-            vt = v_pool[pages].astype(jnp.float32)
-            if k_scale is not None:
-                kt = kt * k_scale[pages][..., None]
-                vt = vt * v_scale[pages][..., None]
-        sc = jnp.einsum("bhrd,bkhd->bhrk", qf, kt,
-                        preferred_element_type=jnp.float32) * scale
-        kv_pos = (t * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (1, ps), 1))[None, None]
-        valid = (kv_pos <= off + row_tok) & (row_tok < cl)
-        sc = jnp.where(valid, sc, -1e30)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.einsum(
-            "bhrk,bkhd->bhrd", p, vt, preferred_element_type=jnp.float32)
-        live = t * ps < total
-        m = jnp.where(live, m_new, m)
-        l = jnp.where(live, l_new, l)
-        acc = jnp.where(live, acc_new, acc)
-    out = acc / jnp.maximum(l, 1e-30)
-    out = jnp.where(row_tok < cl, out, 0.0)
+    seq, live, valid, row_live = _prefill_masks(offset, chunk_len, c, g, ps)
+    seq["pt"] = page_table.astype(jnp.int32)
+    out = _flash_grid(
+        q.astype(jnp.float32).reshape(bsz, hkv, c * g, d), seq,
+        page_table.shape[1], _paged_fetch(k_pool, v_pool, k_scale, v_scale),
+        live, valid,
+        scale=scale if scale is not None else d ** -0.5,
+        kv_bits=kv_format(k_pool, k_scale, v_scale))
+    out = jnp.where(row_live, out, 0.0)
     return out.reshape(bsz, hkv, c, g, d).astype(out_dtype or q.dtype)
 
 

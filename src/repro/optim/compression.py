@@ -16,7 +16,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -49,7 +48,7 @@ def make_int8_psum(mesh: Mesh, axes: tuple[str, ...] = ("data",)):
 
     def compress(grads: PyTree) -> PyTree:
         spec = P()   # replicated view within shard_map over reduction axes
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda g: jax.tree_util.tree_map(_reduce_leaf, g),
             mesh=mesh,
             in_specs=(spec,), out_specs=spec,

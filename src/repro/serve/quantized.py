@@ -103,7 +103,16 @@ def quantize_lm_packed(params: dict, cfg: ModelConfig, qcfg: QuantConfig
     out = {"embed": params["embed"], "ln_f": params["ln_f"]}
     if "head" in params:
         out["head"] = params["head"]
-    lp = params["layers"]
+    out["layers"] = quantize_layers_packed(params["layers"], cfg, qcfg)
+    return out
+
+
+def quantize_layers_packed(lp: dict, cfg: ModelConfig, qcfg: QuantConfig
+                           ) -> dict:
+    """The layer half of :func:`quantize_lm_packed`: a stacked fp layers
+    subtree (any number of layers) -> its packed serving subtree.  Lets a
+    caller pack a model layer by layer without ever holding the fp tree
+    of the whole model."""
     new_lp = {}
     for k in ("ln_attn", "ln_mlp", "attn_t", "mlp_t"):
         if k in lp:
@@ -126,8 +135,7 @@ def quantize_lm_packed(params: dict, cfg: ModelConfig, qcfg: QuantConfig
         for k in ("b_gate", "b_up"):
             if k in lp["mlp"]:
                 new_lp["mlp"][k] = lp["mlp"][k]
-    out["layers"] = new_lp
-    return out
+    return new_lp
 
 
 def _act_transform(t: Optional[dict], h: jax.Array) -> jax.Array:

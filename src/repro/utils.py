@@ -9,6 +9,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+import os
 import time
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
@@ -24,6 +25,28 @@ if not logger.handlers:  # configure once; callers may reconfigure
     logger.setLevel(logging.INFO)
 
 PyTree = Any
+
+# persistent XLA compilation cache when JAX_COMPILATION_CACHE_DIR is unset:
+# a fixed directory in the checkout, so reruns of the same code hit it
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads the
+    variable itself) and no other directory is set; otherwise the cache
+    lives at :data:`COMPILE_CACHE_DIR` inside the checkout.  Returns the
+    directory in use.  Call it from ``__main__`` blocks, not from library
+    code, so tests that import the entry points leave the cache alone.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ import pytest
 
 # NOTE: x64 is NOT enabled globally (it would change default dtypes across
 # the whole suite); the fp64 merge-error test (paper Table 4) uses the
-# jax.experimental.enable_x64 scoped context instead.
+# jax.enable_x64 scoped context instead.
 
 
 @pytest.fixture(scope="session")

@@ -50,14 +50,13 @@ def test_fuse_effective_weight_identity_without_quant():
 def test_merge_error_fp32_vs_fp64():
     """Paper Table 4: fp64 inverse merge error << fp32 merge error, and the
     strictly-diagonally-dominant structure keeps BOTH tiny."""
-    from jax.experimental import enable_x64
     key = jax.random.PRNGKey(3)
     h = 128
     a = jnp.eye(h) + 0.2 * jax.random.normal(key, (h, h)) / h
     w = jax.random.normal(jax.random.fold_in(key, 1), (h, h))
     x = jax.random.normal(jax.random.fold_in(key, 2), (64, h))
     err32 = float(eq.merge_error(x, w, a, solve_dtype=jnp.float32))
-    with enable_x64():
+    with jax.enable_x64(True):
         err64 = float(eq.merge_error(jnp.asarray(np.asarray(x)),
                                      jnp.asarray(np.asarray(w)),
                                      jnp.asarray(np.asarray(a)),
