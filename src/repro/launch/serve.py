@@ -42,7 +42,10 @@ per-request wall-clock deadlines (expired requests finish
 ``FAILED_DEADLINE`` with their partial stream) and bounded-queue
 backpressure (rejected submits are logged, not raised as tracebacks).
 Whenever any request ends non-``COMPLETED`` the launcher prints a
-per-status histogram next to the throughput line.
+per-status histogram next to the throughput line.  Each run also logs the
+engine's host time per step by phase: the self time of each
+``serve.*`` span of ``Engine.span_records`` (``repro.serve.spans``), so
+``decode.wait`` is the time the host waited on the device.
 
 ``--paged`` serves through the page-table KV cache (DESIGN.md §9): the
 engine allocates fixed-size pages (``--page-size``) from a global pool on
@@ -74,6 +77,7 @@ from repro.core.calibration import CalibConfig, quantize_dense_model
 from repro.core.quantizer import QuantConfig
 from repro.data import MarkovCorpus
 from repro.models import build_model
+from repro.serve import spans
 from repro.serve.engine import Engine, QueueFull, RequestStatus, ServeConfig
 from repro.train import checkpoints
 from repro.utils import logger
@@ -227,6 +231,9 @@ def main(argv=None) -> int:
                                sorted(eng.status_counts().items()))
             logger.warning("[%s] %d/%d requests completed (%s)", tag,
                            len(ok), len(done), counts)
+        per = spans.phase_ms(eng.span_records())
+        logger.info("[%s] host ms per step: %s", tag, ", ".join(
+            f"{k.removeprefix('serve.')} {v:.3f}" for k, v in per.items()))
         return [r.out_tokens for r in done], eng
 
     def agreement(a_outs, b_outs):

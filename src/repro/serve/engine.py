@@ -81,8 +81,15 @@ and reports the matched token count; ``_seq_len`` starts there, so
 drops proportionally to the hit rate, token-identically to the no-sharing
 engine.  Preemption and completion just drop references; FAILED_NAN
 retirement quarantines co-readers of shared pages instead of scrubbing
-live KV (see ``_retire_slot``).  ``prefix_stats`` feeds the serve_bench
-``prefix`` rows.
+live KV (see ``_retire_slot``).  ``prefix_stats`` is read by
+``launch/serve.py``'s prefix-cache line and by the tests.
+
+Host spans (``repro.serve.spans``): ``step`` records each phase of the
+iteration — ``serve.step`` with ``serve.deadlines``, ``serve.admit``,
+``serve.prefill`` (and ``serve.prefill.wait`` around a final chunk's
+readback), ``serve.capacity``, ``serve.decode.dispatch``,
+``serve.decode.wait`` and ``serve.emit`` inside it — in an always-on
+bounded ring; :meth:`Engine.span_records` returns them.
 
 Quantized serving: pass a ``repro.serve.quantized.QuantizedModel`` over a
 QTensor tree — ``Model`` and ``QuantizedModel`` expose the same
@@ -112,6 +119,7 @@ from repro import sharding
 from repro.models.model import Model
 from repro.serve import faults as flt
 from repro.serve import kv_cache
+from repro.serve import spans as spans_lib
 from repro.utils import logger, next_multiple
 
 
@@ -308,8 +316,9 @@ class Engine:
         self._step_idx = 0
         self._watchdog = 0       # consecutive steps without progress
         self._progress = 0       # tokens streamed + chunks + retirements
-        # prefix-cache accounting (serve_bench `prefix` rows): one lookup
-        # per chunked admission, matched tokens skip prefill entirely
+        self._spans = spans_lib.SpanRecorder()
+        # prefix-cache accounting (launch/serve.py's prefix line): one
+        # lookup per chunked admission, matched tokens skip prefill entirely
         self.prefix_stats = {"lookups": 0, "hits": 0,
                              "matched_tokens": 0, "prefilled_tokens": 0}
         if cfg.prefill_chunk:
@@ -438,6 +447,13 @@ class Engine:
         return {"device_count": int(n),
                 "weight_bytes_per_device": per_device(self.params),
                 "kv_bytes_per_device": per_device(self._kv.cache)}
+
+    def span_records(self, t0: Optional[float] = None,
+                     t1: Optional[float] = None) -> list[spans_lib.Span]:
+        """The host spans of the steps between ``time.perf_counter()``
+        times ``t0`` and ``t1`` (None = unbounded), from the newest
+        ``repro.serve.spans.CAPACITY`` kept."""
+        return self._spans.records(t0, t1)
 
     # ------------------------------------------------------------------
     # termination plumbing (the ONLY places a request goes terminal)
@@ -592,9 +608,10 @@ class Engine:
         is numerically neutral); ``ok`` rides the sampled-token transfer,
         so NaN detection costs no extra device sync."""
         logits, cache = self.model.decode_step(params, tok, cache)
-        lg = logits[:, -1, :] + poison[:, None]
-        ok = jnp.all(jnp.isfinite(lg), axis=-1)
-        nxt, ok = self._pin_replicated(self._sample(lg, keys), ok)
+        with jax.named_scope("sample"):
+            lg = logits[:, -1, :] + poison[:, None]
+            ok = jnp.all(jnp.isfinite(lg), axis=-1)
+            nxt, ok = self._pin_replicated(self._sample(lg, keys), ok)
         return nxt, ok, self._pin_cache(cache)
 
     def _poison(self, active: list[int]) -> jax.Array:
@@ -626,76 +643,90 @@ class Engine:
         return [i for i, s in enumerate(self._slots) if s is None]
 
     def _admit(self) -> None:
-        self._shed_unservable()
-        free = self._free_slots()
-        while free and self._pending:
-            # FIFO prefix run sharing one bucket -> one batched prefill
-            bucket = self._bucket(self._pending[0].resume_len)
-            group: list[Request] = []
-            while (self._pending and len(group) < len(free)
-                   and self._bucket(self._pending[0].resume_len) == bucket):
-                group.append(self._pending.popleft())
-            # paged: reserve prompt pages up front; requests that do not
-            # fit go back to the queue head (FIFO order preserved)
-            fitted: list[tuple[int, Request, int]] = []
-            for req in group:
-                slot = free[len(fitted)]
-                if not self._kv.reserve(slot, req.resume_len):
-                    break
-                fitted.append((slot, req, req.resume_len))
-            overflow = group[len(fitted):]
-            self._pending.extendleft(reversed(overflow))
-            if not fitted:
-                # pool (transiently) dry: wait for completions to free
-                # pages; a queue that can never drain trips the watchdog
-                return
-            free = free[len(fitted):]
+        with self._spans.span("serve.admit", rids=[]) as sp:
+            self._shed_unservable()
+            free = self._free_slots()
+            while free and self._pending:
+                # FIFO prefix run sharing one bucket -> one batched prefill
+                bucket = self._bucket(self._pending[0].resume_len)
+                group: list[Request] = []
+                while (self._pending and len(group) < len(free)
+                       and self._bucket(self._pending[0].resume_len)
+                       == bucket):
+                    group.append(self._pending.popleft())
+                # paged: reserve prompt pages up front; requests that do
+                # not fit go back to the queue head (FIFO order preserved)
+                fitted: list[tuple[int, Request, int]] = []
+                for req in group:
+                    slot = free[len(fitted)]
+                    if not self._kv.reserve(slot, req.resume_len):
+                        break
+                    fitted.append((slot, req, req.resume_len))
+                overflow = group[len(fitted):]
+                self._pending.extendleft(reversed(overflow))
+                if not fitted:
+                    # pool (transiently) dry: wait for completions to free
+                    # pages; a queue that can never drain trips the
+                    # watchdog
+                    return
+                free = free[len(fitted):]
+                rids = [req.rid for _, req, _ in fitted]
+                sp.attrs["rids"] += rids
+                with self._spans.span(
+                        "serve.prefill", rids=rids, offset=0,
+                        tokens=sum(ln for _, _, ln in fitted)):
+                    free.extend(self._prefill_group(fitted, bucket))
 
-            tokens = np.zeros((len(fitted), bucket), np.int32)
-            lengths = np.asarray([ln for _, _, ln in fitted], np.int32)
-            for row, (_, req, ln) in enumerate(fitted):
-                tokens[row, :ln] = req.resume_tokens()
-            with self._bound():
-                logits, cache1 = self._prefill(
-                    self.params, jnp.asarray(tokens),
-                    jnp.asarray(lengths) if self._supports_padded else None,
-                    bucket)
-            lg = logits[:, -1, :]
-            if self._faults is not None:
-                pv = np.zeros((len(fitted),), np.float32)
-                for row, (_, req, _) in enumerate(fitted):
-                    if self._faults.fires(flt.NAN_LOGITS, rid=req.rid):
-                        pv[row] = np.nan
-                if np.isnan(pv).any():
-                    lg = lg + jnp.asarray(pv)[:, None]
-            ok_dev = jnp.all(jnp.isfinite(lg), axis=-1)
-            toks, ok = jax.device_get((self._sample(
-                lg, self._req_keys([r for _, r, _ in fitted])), ok_dev))
-            slot_ids, slot_toks, assigned = [], [], []
-            for row, (slot, req, ln) in enumerate(fitted):
-                self._kv.splice(slot, cache1, row, int(ln))
-                self._slots[slot] = req
-                self._seq_len[slot] = int(ln)
-                req.status = RequestStatus.RUNNING
-                assigned.append(slot)
-                if not bool(ok[row]):
-                    self._retire_slot(slot, RequestStatus.FAILED_NAN,
-                                      error="non-finite logits at prefill")
-                    continue
-                tok = int(toks[row])
-                if not self._dispatch_token(req, tok):
-                    self._retire_slot(slot, RequestStatus.FAILED_CALLBACK,
-                                      error="on_token callback raised")
-                    continue
-                slot_ids.append(slot)
-                slot_toks.append(tok)
-                self._maybe_finish(slot, tok)
-            if slot_ids:
-                self._last_tok = self._last_tok.at[
-                    jnp.asarray(slot_ids), 0].set(jnp.asarray(slot_toks))
-            # a request can retire straight from prefill (EOS / max_new=1 /
-            # quarantine): hand its slot back so this pass can refill it
-            free.extend(s for s in assigned if self._slots[s] is None)
+    def _prefill_group(self, fitted: list, bucket: int) -> list[int]:
+        """Prefill one bucket's ``(slot, request, length)`` group in one
+        batched call, sample each first token and hand the slots to
+        decode.  Returns the slots that retired straight from prefill."""
+        tokens = np.zeros((len(fitted), bucket), np.int32)
+        lengths = np.asarray([ln for _, _, ln in fitted], np.int32)
+        for row, (_, req, ln) in enumerate(fitted):
+            tokens[row, :ln] = req.resume_tokens()
+        with self._bound():
+            logits, cache1 = self._prefill(
+                self.params, jnp.asarray(tokens),
+                jnp.asarray(lengths) if self._supports_padded else None,
+                bucket)
+        lg = logits[:, -1, :]
+        if self._faults is not None:
+            pv = np.zeros((len(fitted),), np.float32)
+            for row, (_, req, _) in enumerate(fitted):
+                if self._faults.fires(flt.NAN_LOGITS, rid=req.rid):
+                    pv[row] = np.nan
+            if np.isnan(pv).any():
+                lg = lg + jnp.asarray(pv)[:, None]
+        ok_dev = jnp.all(jnp.isfinite(lg), axis=-1)
+        toks_dev = self._sample(lg, self._req_keys([r for _, r, _ in fitted]))
+        with self._spans.span("serve.prefill.wait"):
+            toks, ok = jax.device_get((toks_dev, ok_dev))
+        slot_ids, slot_toks, assigned = [], [], []
+        for row, (slot, req, ln) in enumerate(fitted):
+            self._kv.splice(slot, cache1, row, int(ln))
+            self._slots[slot] = req
+            self._seq_len[slot] = int(ln)
+            req.status = RequestStatus.RUNNING
+            assigned.append(slot)
+            if not bool(ok[row]):
+                self._retire_slot(slot, RequestStatus.FAILED_NAN,
+                                  error="non-finite logits at prefill")
+                continue
+            tok = int(toks[row])
+            if not self._dispatch_token(req, tok):
+                self._retire_slot(slot, RequestStatus.FAILED_CALLBACK,
+                                  error="on_token callback raised")
+                continue
+            slot_ids.append(slot)
+            slot_toks.append(tok)
+            self._maybe_finish(slot, tok)
+        if slot_ids:
+            self._last_tok = self._last_tok.at[
+                jnp.asarray(slot_ids), 0].set(jnp.asarray(slot_toks))
+        # a request can retire straight from prefill (EOS / max_new=1 /
+        # quarantine): hand its slot back so this pass can refill it
+        return [s for s in assigned if self._slots[s] is None]
 
     # ------------------------------------------------------------------
     # chunked admission (ServeConfig.prefill_chunk > 0)
@@ -707,31 +738,34 @@ class Engine:
         prompt never monopolizes the step loop.  Paged mode reserves the
         prompt's pages up front exactly like whole-prompt admission (same
         free-list accounting, same preemption sizes)."""
-        self._shed_unservable()
-        for slot in self._free_slots():
-            if not self._pending:
-                return
-            req = self._pending[0]
-            toks = req.resume_tokens()
-            if not self._kv.reserve(slot, req.resume_len, tokens=toks):
-                # pool (transiently) dry: wait for completions to free
-                # pages; a queue that can never drain trips the watchdog
-                return
-            self._pending.popleft()
-            self._slots[slot] = req
-            # prefix hit: the matched tokens are already resident in shared
-            # pages — chunked prefill resumes at the first novel token
-            # (matched is a page multiple, so the slot's writes only ever
-            # touch its fresh exclusive pages)
-            matched = self._kv.matched_tokens(slot)
-            self._seq_len[slot] = matched
-            req.status = RequestStatus.RUNNING
-            self._prefill_prog[slot] = (req, toks)
-            if self.cfg.prefix_cache:
-                st = self.prefix_stats
-                st["lookups"] += 1
-                st["hits"] += int(matched > 0)
-                st["matched_tokens"] += matched
+        with self._spans.span("serve.admit", rids=[]) as sp:
+            self._shed_unservable()
+            for slot in self._free_slots():
+                if not self._pending:
+                    return
+                req = self._pending[0]
+                toks = req.resume_tokens()
+                if not self._kv.reserve(slot, req.resume_len, tokens=toks):
+                    # pool (transiently) dry: wait for completions to free
+                    # pages; a queue that can never drain trips the
+                    # watchdog
+                    return
+                self._pending.popleft()
+                self._slots[slot] = req
+                sp.attrs["rids"].append(req.rid)
+                # prefix hit: the matched tokens are already resident in
+                # shared pages — chunked prefill resumes at the first novel
+                # token (matched is a page multiple, so the slot's writes
+                # only ever touch its fresh exclusive pages)
+                matched = self._kv.matched_tokens(slot)
+                self._seq_len[slot] = matched
+                req.status = RequestStatus.RUNNING
+                self._prefill_prog[slot] = (req, toks)
+                if self.cfg.prefix_cache:
+                    st = self.prefix_stats
+                    st["lookups"] += 1
+                    st["hits"] += int(matched > 0)
+                    st["matched_tokens"] += matched
 
     def _advance_prefill(self) -> bool:
         """Advance the FIFO-oldest mid-prefill slot by one chunk of up to
@@ -749,52 +783,56 @@ class Engine:
         done = self._seq_len[slot]          # tokens written so far
         c = self.cfg.prefill_chunk
         n = min(c, len(toks) - done)
-        tokens = np.zeros((self.cfg.max_batch, c), np.int32)
-        tokens[slot, :n] = toks[done:done + n]
-        chunk_len = np.zeros((self.cfg.max_batch,), np.int32)
-        chunk_len[slot] = n
-        # every row passes its host-known true length: rows with
-        # chunk_len == 0 neither write nor attend, and the chunk call
-        # resyncs their device lens (decode steps write a droppable
-        # garbage token ahead of mid-prefill slots — the next chunk
-        # overwrites it before it is ever attended)
-        offsets = np.asarray(self._seq_len, np.int32)
-        with self._bound():
-            logits, cache = self._chunk(
-                self.params, jnp.asarray(tokens), jnp.asarray(chunk_len),
-                self._kv.cache, jnp.asarray(offsets))
-        self._kv.cache = cache
-        self._seq_len[slot] = done + n
-        self._progress += 1
-        self.prefix_stats["prefilled_tokens"] += n
-        if done + n < len(toks):
-            return True
-        # prompt fully prefilled: sample the first token from the last
-        # valid chunk row (the chunk call already gathered it) and start
-        # decoding.  The finiteness flag rides the same readback as the
-        # sampled token — only this final chunk ever syncs.
-        self._prefill_prog[slot] = None
-        lg = logits[slot]
-        if self._faults is not None and self._faults.fires(
-                flt.NAN_LOGITS, rid=req.rid, slot=slot):
-            lg = lg + jnp.float32(np.nan)
-        tok_arr = self._sample(lg, self._req_keys([req]))
-        tok_host, ok = jax.device_get((tok_arr, jnp.all(jnp.isfinite(lg))))
-        if not bool(ok):
-            self._retire_slot(slot, RequestStatus.FAILED_NAN,
-                              error="non-finite logits at prefill")
-            return True
-        # finite final-row logits certify every attended K/V row finite
-        # (a NaN anywhere within lens would have propagated) — only now
-        # may the sequence's full pages enter the prefix map
-        self._kv.register_prefix(slot, toks)
-        tok = int(tok_host[0])
-        if not self._dispatch_token(req, tok):
-            self._retire_slot(slot, RequestStatus.FAILED_CALLBACK,
-                              error="on_token callback raised")
-            return True
-        self._last_tok = self._last_tok.at[slot, 0].set(tok)
-        self._maybe_finish(slot, tok)
+        with self._spans.span("serve.prefill", rid=req.rid, tokens=n,
+                              offset=done):
+            tokens = np.zeros((self.cfg.max_batch, c), np.int32)
+            tokens[slot, :n] = toks[done:done + n]
+            chunk_len = np.zeros((self.cfg.max_batch,), np.int32)
+            chunk_len[slot] = n
+            # every row passes its host-known true length: rows with
+            # chunk_len == 0 neither write nor attend, and the chunk call
+            # resyncs their device lens (decode steps write a droppable
+            # garbage token ahead of mid-prefill slots — the next chunk
+            # overwrites it before it is ever attended)
+            offsets = np.asarray(self._seq_len, np.int32)
+            with self._bound():
+                logits, cache = self._chunk(
+                    self.params, jnp.asarray(tokens), jnp.asarray(chunk_len),
+                    self._kv.cache, jnp.asarray(offsets))
+            self._kv.cache = cache
+            self._seq_len[slot] = done + n
+            self._progress += 1
+            self.prefix_stats["prefilled_tokens"] += n
+            if done + n < len(toks):
+                return True
+            # prompt fully prefilled: sample the first token from the last
+            # valid chunk row (the chunk call already gathered it) and
+            # start decoding.  The finiteness flag rides the same readback
+            # as the sampled token — only this final chunk ever syncs.
+            self._prefill_prog[slot] = None
+            lg = logits[slot]
+            if self._faults is not None and self._faults.fires(
+                    flt.NAN_LOGITS, rid=req.rid, slot=slot):
+                lg = lg + jnp.float32(np.nan)
+            tok_arr = self._sample(lg, self._req_keys([req]))
+            ok_dev = jnp.all(jnp.isfinite(lg))
+            with self._spans.span("serve.prefill.wait"):
+                tok_host, ok = jax.device_get((tok_arr, ok_dev))
+            if not bool(ok):
+                self._retire_slot(slot, RequestStatus.FAILED_NAN,
+                                  error="non-finite logits at prefill")
+                return True
+            # finite final-row logits certify every attended K/V row finite
+            # (a NaN anywhere within lens would have propagated) — only now
+            # may the sequence's full pages enter the prefix map
+            self._kv.register_prefix(slot, toks)
+            tok = int(tok_host[0])
+            if not self._dispatch_token(req, tok):
+                self._retire_slot(slot, RequestStatus.FAILED_CALLBACK,
+                                  error="on_token callback raised")
+                return True
+            self._last_tok = self._last_tok.at[slot, 0].set(tok)
+            self._maybe_finish(slot, tok)
         return True
 
     # ------------------------------------------------------------------
@@ -836,20 +874,22 @@ class Engine:
         is the biggest reclaim) when the pool runs dry.  A preempted
         mid-prefill request restarts through the same chunked path on
         resume, token-identically."""
-        for slot in list(active):
-            if self._slots[slot] is None:
-                continue
-            while not self._kv.ensure_append(slot, self._seq_len[slot]):
-                live = [i for i, s in enumerate(self._slots)
-                        if s is not None]
-                # rank victims by what their eviction actually frees:
-                # shared pages survive the free (their other readers keep
-                # them live), so only exclusively-owned pages count
-                victim = max(live, key=lambda i: (
-                    self._kv.reclaimable_pages(i), self._seq_len[i], -i))
-                self._preempt(victim)
-                if victim == slot:
-                    break
+        with self._spans.span("serve.capacity", preempted=[]) as sp:
+            for slot in list(active):
+                if self._slots[slot] is None:
+                    continue
+                while not self._kv.ensure_append(slot, self._seq_len[slot]):
+                    live = [i for i, s in enumerate(self._slots)
+                            if s is not None]
+                    # rank victims by what their eviction actually frees:
+                    # shared pages survive the free (their other readers
+                    # keep them live), so only exclusively-owned pages count
+                    victim = max(live, key=lambda i: (
+                        self._kv.reclaimable_pages(i), self._seq_len[i], -i))
+                    sp.attrs["preempted"].append(self._slots[victim].rid)
+                    self._preempt(victim)
+                    if victim == slot:
+                        break
         return [i for i in active if self._slots[i] is not None]
 
     # ------------------------------------------------------------------
@@ -893,22 +933,40 @@ class Engine:
         work with every decode step, so in-flight decodes keep streaming
         while a long prompt drips in.  Returns the number of sequences
         advanced."""
-        progress0 = self._progress
-        if self._faults is not None:
-            self._faults.begin_step(self._step_idx)
-        self._check_deadlines()
-        if self.cfg.prefill_chunk:
-            self._admit_chunked()
-            did_chunk = self._advance_prefill()
-        else:
-            self._admit()
-            did_chunk = False
-        active = [i for i, s in enumerate(self._slots)
-                  if s is not None and self._prefill_prog[i] is None]
-        if self.cfg.paged:
-            active = self._ensure_capacity(active)
-        advanced = 0
-        if active:
+        with self._spans.span("serve.step", step=self._step_idx):
+            progress0 = self._progress
+            if self._faults is not None:
+                self._faults.begin_step(self._step_idx)
+            with self._spans.span("serve.deadlines"):
+                self._check_deadlines()
+            if self.cfg.prefill_chunk:
+                self._admit_chunked()
+                did_chunk = self._advance_prefill()
+            else:
+                self._admit()
+                did_chunk = False
+            active = [i for i, s in enumerate(self._slots)
+                      if s is not None and self._prefill_prog[i] is None]
+            if self.cfg.paged:
+                active = self._ensure_capacity(active)
+            if active:
+                self._decode_active(active)
+            self._step_idx += 1
+            if self._progress == progress0 and (
+                    self._pending or any(s is not None for s in self._slots)):
+                self._watchdog += 1
+                if self._watchdog > self.cfg.watchdog_steps:
+                    self._degrade()
+            else:
+                self._watchdog = 0
+            return len(active) + int(did_chunk)
+
+    def _decode_active(self, active: list[int]) -> None:
+        """One batched decode step over the ``active`` slots: dispatch,
+        read the sampled tokens back, stream them and retire."""
+        with self._spans.span(
+                "serve.decode.dispatch", rows=len(active),
+                context=sum(self._seq_len[i] + 1 for i in active)):
             reqs = [self._slots[i] if (self._slots[i] is not None
                                        and self._prefill_prog[i] is None)
                     else _IDLE_REQ for i in range(self.cfg.max_batch)]
@@ -918,7 +976,9 @@ class Engine:
                     self._req_keys(reqs), self._poison(active))
             self._kv.cache = cache
             self._last_tok = nxt[:, None]
+        with self._spans.span("serve.decode.wait"):
             nxt_host, ok = jax.device_get((nxt, ok_dev))
+        with self._spans.span("serve.emit"):
             for i in active:
                 req = self._slots[i]
                 if req is None:
@@ -940,16 +1000,6 @@ class Engine:
                     continue
                 self._seq_len[i] += 1
                 self._maybe_finish(i, tok)
-            advanced = len(active)
-        self._step_idx += 1
-        if self._progress == progress0 and (
-                self._pending or any(s is not None for s in self._slots)):
-            self._watchdog += 1
-            if self._watchdog > self.cfg.watchdog_steps:
-                self._degrade()
-        else:
-            self._watchdog = 0
-        return advanced + int(did_chunk)
 
     def run(self, max_steps: int = 0) -> list[Request]:
         """Drain the queue; returns every submitted request, in

@@ -310,11 +310,9 @@ class QuantizedModel:
             params, tokens, lengths, cache, jnp.zeros((bsz,), jnp.int32))
         # gather the last valid hidden row BEFORE the head: whole-prompt
         # prefill never materializes (B, T, vocab) logits
-        x = x[jnp.arange(bsz), lengths - 1][:, None]
-        x = layers.apply_norm(params["ln_f"], x, self.cfg.norm)
-        head = params.get("head")
-        logits = x @ (head if head is not None else params["embed"].T)
-        return logits, cache
+        with jax.named_scope("head"):
+            x = x[jnp.arange(bsz), lengths - 1][:, None]
+            return self._head(params, x), cache
 
     def prefill_chunk(self, params, batch, cache, offset, *,
                       last_only: bool = False):
@@ -334,7 +332,6 @@ class QuantizedModel:
         last valid row (pre-head, like :meth:`prefill`) when ``last_only``
         (static): the engine's chunk steps only ever read that row, so
         they skip the (B, C, vocab) head matmul."""
-        cfg = self.cfg
         tokens = batch["tokens"]
         bsz, c = tokens.shape
         chunk_len = batch.get("chunk_len")
@@ -342,12 +339,11 @@ class QuantizedModel:
                      else jnp.asarray(chunk_len, jnp.int32))
         x, cache = self._forward_chunk(params, tokens, chunk_len, cache,
                                        offset)
-        if last_only:
-            x = x[jnp.arange(bsz), jnp.maximum(chunk_len - 1, 0)][:, None]
-        x = layers.apply_norm(params["ln_f"], x, cfg.norm)
-        head = params.get("head")
-        logits = x @ (head if head is not None else params["embed"].T)
-        return logits, cache
+        with jax.named_scope("head"):
+            if last_only:
+                x = x[jnp.arange(bsz),
+                      jnp.maximum(chunk_len - 1, 0)][:, None]
+            return self._head(params, x), cache
 
     def _forward_chunk(self, params, tokens, chunk_len, cache, offset):
         """Chunk trunk shared by :meth:`prefill` and :meth:`prefill_chunk`:
@@ -361,10 +357,11 @@ class QuantizedModel:
         offset = jnp.asarray(offset, jnp.int32)
         chunk_len = jnp.asarray(chunk_len, jnp.int32)
         paged = isinstance(cache, PagedKVCache)
-        x = jnp.take(params["embed"], tokens, axis=0)
-        pos = offset[:, None] + jnp.arange(c)[None, :]
-        if cfg.rope_theta == 0:
-            x = x + sinusoidal_at(pos, cfg.d_model).astype(x.dtype)
+        with jax.named_scope("embed"):
+            pos = offset[:, None] + jnp.arange(c)[None, :]
+            x = jnp.take(params["embed"], tokens, axis=0)
+            if cfg.rope_theta == 0:
+                x = x + sinusoidal_at(pos, cfg.d_model).astype(x.dtype)
         if paged:
             kv_in = (cache.k, cache.v)
             if self._kv_quantized:
@@ -382,10 +379,7 @@ class QuantizedModel:
                                               chunk_len, pt, psz)
             return h, kv
 
-        if cfg.scan_layers:
-            x, kv_new = jax.lax.scan(body, x, (params["layers"],) + kv_in)
-        else:
-            raise NotImplementedError("packed serving assumes scan layout")
+        x, kv_new = self._decoder(body, x, params["layers"], kv_in)
         if paged:
             new = {"k": kv_new[0], "v": kv_new[1],
                    "lens": jnp.minimum(offset + chunk_len, cache.capacity)}
@@ -404,52 +398,42 @@ class QuantizedModel:
         from repro.serve.kv_cache import (chunk_write_dest,
                                           linear_chunk_write_dest,
                                           paged_chunk_write)
-        cfg = self.cfg
         b, c = x.shape[0], x.shape[1]
-        h = layers.apply_norm(p["ln_attn"], x, cfg.norm)
-        h = _act_transform(p.get("attn_t"), h)
-        q = self._mm(h, p["wq"])
-        k = self._mm(h, p["wk"])
-        v = self._mm(h, p["wv"])
-        if "bq" in p:
-            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        hd = cfg.resolved_head_dim
-        q = q.reshape(b, c, cfg.num_heads, hd)
-        k = k.reshape(b, c, cfg.num_kv_heads, hd)
-        v = v.reshape(b, c, cfg.num_kv_heads, hd)
-        if cfg.rope_theta > 0:
-            q = layers.apply_rope(q, pos, cfg.rope_theta)
-            k = layers.apply_rope(k, pos, cfg.rope_theta)
-        if page_table is not None:
-            num_pages = kv[0].shape[0]
-            dest = chunk_write_dest(page_table, offset, chunk_len, c,
-                                    page_size, num_pages)
-            write = lambda pool, val: paged_chunk_write(pool, val, dest)
-        else:
-            # pad rows / past-capacity positions resolve OOB: scatter drops
-            dest = linear_chunk_write_dest(offset, chunk_len, c,
-                                           kv[0].shape[1])
-            bidx = jnp.arange(b)[:, None]
-            write = lambda ct, val: ct.at[bidx, dest].set(val.astype(ct.dtype))
-        if len(kv) == 4:
-            # fused quantize-on-write: the chunk enters the cache as codes
-            kc, vc, ksc, vsc = kv
-            kq, k_s = _kv_quantize(k, self.qcfg.kv_bits)
-            vq, v_s = _kv_quantize(v, self.qcfg.kv_bits)
-            kv = (write(kc, kq), write(vc, vq),
-                  write(ksc, k_s), write(vsc, v_s))
-        else:
-            kc, vc = kv
-            kv = (write(kc, k), write(vc, v))
+        q, k, v = self._qkv(p, x, pos)
+        with jax.named_scope("kv_write"):
+            if page_table is not None:
+                num_pages = kv[0].shape[0]
+                dest = chunk_write_dest(page_table, offset, chunk_len, c,
+                                        page_size, num_pages)
+                write = lambda pool, val: paged_chunk_write(pool, val, dest)
+            else:
+                # pad rows / past-capacity positions resolve OOB: scatter
+                # drops
+                dest = linear_chunk_write_dest(offset, chunk_len, c,
+                                               kv[0].shape[1])
+                bidx = jnp.arange(b)[:, None]
+                write = lambda ct, val: ct.at[bidx, dest].set(
+                    val.astype(ct.dtype))
+            if len(kv) == 4:
+                # fused quantize-on-write: the chunk enters the cache as
+                # codes
+                kc, vc, ksc, vsc = kv
+                kq, k_s = _kv_quantize(k, self.qcfg.kv_bits)
+                vq, v_s = _kv_quantize(v, self.qcfg.kv_bits)
+                kv = (write(kc, kq), write(vc, vq),
+                      write(ksc, k_s), write(vsc, v_s))
+            else:
+                kc, vc = kv
+                kv = (write(kc, k), write(vc, v))
         # attention reads the cache AS STORED (prefix + this chunk):
         # in-register tile dequant, chunk-end-masked KV grid — bit-identical
         # per row to flash_decode over the same cache (resume exactness)
-        out = ops.flash_prefill(q, kv, offset, chunk_len,
-                                block_kv=self.flash_block_kv,
-                                page_table=page_table, mode=self.kernel_mode)
-        x = x + self._mm(out.reshape(b, c, -1), p["wo"])
-        x = x + self._mlp(p, x)
-        return x, kv
+        with jax.named_scope("attn.flash"):
+            out = ops.flash_prefill(q, kv, offset, chunk_len,
+                                    block_kv=self.flash_block_kv,
+                                    page_table=page_table,
+                                    mode=self.kernel_mode)
+        return self._out_and_mlp(p, x, out), kv
 
     # ------------------------------------------------------------------
     # decode
@@ -458,13 +442,8 @@ class QuantizedModel:
         from repro.serve.kv_cache import PagedKVCache
         if isinstance(cache, PagedKVCache):
             return self._decode_step_paged(params, token, cache)
-        cfg = self.cfg
-        x = jnp.take(params["embed"], token, axis=0)
         cur_len = cache["len"]
-        if cfg.rope_theta == 0:
-            pe = sinusoidal_at(cur_len, cfg.d_model)
-            x = x + pe[:, None, :].astype(x.dtype)
-
+        x = self._embed_token(params, token, cur_len)
         if self._kv_quantized:
             kv_in = (cache["k"], cache["v"],
                      cache["k_scale"], cache["v_scale"])
@@ -476,13 +455,9 @@ class QuantizedModel:
             h, kv = self._block_decode(lp, h, kv, cur_len)
             return h, kv
 
-        if cfg.scan_layers:
-            x, kv_new = jax.lax.scan(body, x, (params["layers"],) + kv_in)
-        else:
-            raise NotImplementedError("packed serving assumes scan layout")
-        x = layers.apply_norm(params["ln_f"], x, cfg.norm)
-        head = params.get("head")
-        logits = x @ (head if head is not None else params["embed"].T)
+        x, kv_new = self._decoder(body, x, params["layers"], kv_in)
+        with jax.named_scope("head"):
+            logits = self._head(params, x)
         # len saturates at capacity: a full cache is observable (len == S),
         # never silently wrapped or overgrown
         s = cache["k"].shape[2]
@@ -492,63 +467,97 @@ class QuantizedModel:
             new_cache["k_scale"], new_cache["v_scale"] = kv_new[2], kv_new[3]
         return logits, new_cache
 
-    def _decode_qkv(self, p, x, cur_len):
-        """Shared decode-step q/k/v half (norm → transform → packed matmuls
-        → RoPE at the absolute position) — one implementation for both
-        cache layouts so the linear and paged paths cannot drift."""
+    def _embed_token(self, params, token, cur_len):
+        """Decode-step embedding of ``token`` (B, 1) at position
+        ``cur_len`` (B,)."""
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], token, axis=0)
+            if self.cfg.rope_theta == 0:
+                pe = sinusoidal_at(cur_len, self.cfg.d_model)
+                x = x + pe[:, None, :].astype(x.dtype)
+        return x
+
+    def _decoder(self, body, x, layer_params, kv_in):
+        """The layer ``scan`` over stacked layer params and KV pools."""
+        if not self.cfg.scan_layers:
+            raise NotImplementedError("packed serving assumes scan layout")
+        with jax.named_scope("decoder"):
+            return jax.lax.scan(body, x, (layer_params,) + kv_in)
+
+    def _head(self, params, x):
+        """Final norm and logits (tied embedding unless ``head``)."""
+        x = layers.apply_norm(params["ln_f"], x, self.cfg.norm)
+        head = params.get("head")
+        return x @ (head if head is not None else params["embed"].T)
+
+    def _qkv(self, p, x, pos):
+        """Shared q/k/v half of every block (norm → transform → packed
+        matmuls → RoPE at the absolute positions ``pos`` (B, T)) — one
+        implementation for prefill and decode and both cache layouts, so
+        the paths cannot drift."""
         cfg = self.cfg
-        h = layers.apply_norm(p["ln_attn"], x, cfg.norm)
-        h = _act_transform(p.get("attn_t"), h)
-        q = self._mm(h, p["wq"])
-        k = self._mm(h, p["wk"])
-        v = self._mm(h, p["wv"])
-        if "bq" in p:
-            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        b = x.shape[0]
-        hd = cfg.resolved_head_dim
-        q = q.reshape(b, 1, cfg.num_heads, hd)
-        k = k.reshape(b, 1, cfg.num_kv_heads, hd)
-        v = v.reshape(b, 1, cfg.num_kv_heads, hd)
-        if cfg.rope_theta > 0:
-            pos = cur_len[:, None]
-            q = layers.apply_rope(q, pos, cfg.rope_theta)
-            k = layers.apply_rope(k, pos, cfg.rope_theta)
+        b, t = x.shape[0], x.shape[1]
+        with jax.named_scope("attn.qkv"):
+            h = layers.apply_norm(p["ln_attn"], x, cfg.norm)
+            h = _act_transform(p.get("attn_t"), h)
+            q = self._mm(h, p["wq"])
+            k = self._mm(h, p["wk"])
+            v = self._mm(h, p["wv"])
+            if "bq" in p:
+                q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+            hd = cfg.resolved_head_dim
+            q = q.reshape(b, t, cfg.num_heads, hd)
+            k = k.reshape(b, t, cfg.num_kv_heads, hd)
+            v = v.reshape(b, t, cfg.num_kv_heads, hd)
+            if cfg.rope_theta > 0:
+                q = layers.apply_rope(q, pos, cfg.rope_theta)
+                k = layers.apply_rope(k, pos, cfg.rope_theta)
         return q, k, v
+
+    def _out_and_mlp(self, p, x, out):
+        """Output projection of the attention ``out`` (B, T, H, D) and the
+        MLP half of a block, each added to the residual ``x``."""
+        b, t = x.shape[0], x.shape[1]
+        with jax.named_scope("attn.out"):
+            x = x + self._mm(out.reshape(b, t, -1), p["wo"])
+        with jax.named_scope("mlp"):
+            return x + self._mlp(p, x)
 
     def _block_decode(self, p, x, kv, cur_len):
         b = x.shape[0]
-        q, k, v = self._decode_qkv(p, x, cur_len)
+        q, k, v = self._qkv(p, x, cur_len[:, None])
         s = kv[0].shape[1]
-        # a full cache drops the write: the saturated index s is out of
-        # bounds and OOB scatter updates are dropped, so slot s-1 is never
-        # clobbered (len saturation in decode_step makes exhaustion visible)
-        write_idx = jnp.minimum(cur_len, s)
-        bidx = jnp.arange(b)
-        if len(kv) == 4:
-            # quantize-on-write (kv_bits < 16); attention reads the codes
-            kc, vc, ksc, vsc = kv
-            kq, k_s = _kv_quantize(k[:, 0], self.qcfg.kv_bits)
-            vq, v_s = _kv_quantize(v[:, 0], self.qcfg.kv_bits)
-            kc = kc.at[bidx, write_idx].set(kq)
-            vc = vc.at[bidx, write_idx].set(vq)
-            ksc = ksc.at[bidx, write_idx].set(k_s)
-            vsc = vsc.at[bidx, write_idx].set(v_s)
-            kv = (kc, vc, ksc, vsc)
-        else:
-            kc, vc = kv
-            kc = kc.at[bidx, write_idx].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[bidx, write_idx].set(v[:, 0].astype(vc.dtype))
-            kv = (kc, vc)
+        with jax.named_scope("kv_write"):
+            # a full cache drops the write: the saturated index s is out
+            # of bounds and OOB scatter updates are dropped, so slot s-1
+            # is never clobbered (len saturation in decode_step makes
+            # exhaustion visible)
+            write_idx = jnp.minimum(cur_len, s)
+            bidx = jnp.arange(b)
+            if len(kv) == 4:
+                # quantize-on-write (kv_bits < 16); attention reads codes
+                kc, vc, ksc, vsc = kv
+                kq, k_s = _kv_quantize(k[:, 0], self.qcfg.kv_bits)
+                vq, v_s = _kv_quantize(v[:, 0], self.qcfg.kv_bits)
+                kc = kc.at[bidx, write_idx].set(kq)
+                vc = vc.at[bidx, write_idx].set(vq)
+                ksc = ksc.at[bidx, write_idx].set(k_s)
+                vsc = vsc.at[bidx, write_idx].set(v_s)
+                kv = (kc, vc, ksc, vsc)
+            else:
+                kc, vc = kv
+                kc = kc.at[bidx, write_idx].set(k[:, 0].astype(kc.dtype))
+                vc = vc.at[bidx, write_idx].set(v[:, 0].astype(vc.dtype))
+                kv = (kc, vc)
         # fused flash-decode over the cache AS STORED: int8 codes dequantized
         # per KV tile in registers, KV grid length-masked to the valid tiles
         # (pallas/interpret/ref); `auto` off-TPU is the portable
         # decode_attention fallback — the only path that materializes fp K/V
-        out = ops.flash_decode(q, kv, jnp.minimum(cur_len + 1, s),
-                               block_kv=self.flash_block_kv,
-                               mode=self.kernel_mode)
-        x = x + self._mm(out.reshape(b, 1, -1), p["wo"])
-        x = x + self._mlp(p, x)
-        return x, kv
+        with jax.named_scope("attn.flash"):
+            out = ops.flash_decode(q, kv, jnp.minimum(cur_len + 1, s),
+                                   block_kv=self.flash_block_kv,
+                                   mode=self.kernel_mode)
+        return self._out_and_mlp(p, x, out), kv
 
     # ------------------------------------------------------------------
     # paged decode (PagedKVCache: page pools + per-sequence page tables)
@@ -558,13 +567,8 @@ class QuantizedModel:
         the sequence's current page (via the page table), attention walks
         only the allocated pages.  Same math as the linear path — only the
         cache addressing differs."""
-        cfg = self.cfg
-        x = jnp.take(params["embed"], token, axis=0)
         cur_len = cache.lens
-        if cfg.rope_theta == 0:
-            pe = sinusoidal_at(cur_len, cfg.d_model)
-            x = x + pe[:, None, :].astype(x.dtype)
-
+        x = self._embed_token(params, token, cur_len)
         if self._kv_quantized:
             kv_in = (cache.k, cache.v, cache.k_scale, cache.v_scale)
         else:
@@ -577,13 +581,9 @@ class QuantizedModel:
                                              cache.page_size)
             return h, kv
 
-        if cfg.scan_layers:
-            x, kv_new = jax.lax.scan(body, x, (params["layers"],) + kv_in)
-        else:
-            raise NotImplementedError("packed serving assumes scan layout")
-        x = layers.apply_norm(params["ln_f"], x, cfg.norm)
-        head = params.get("head")
-        logits = x @ (head if head is not None else params["embed"].T)
+        x, kv_new = self._decoder(body, x, params["layers"], kv_in)
+        with jax.named_scope("head"):
+            logits = self._head(params, x)
         new = {"k": kv_new[0], "v": kv_new[1],
                "lens": jnp.minimum(cur_len + 1, cache.capacity)}
         if self._kv_quantized:
@@ -592,32 +592,32 @@ class QuantizedModel:
 
     def _block_decode_paged(self, p, x, kv, cur_len, page_table, page_size):
         from repro.serve.kv_cache import paged_token_write, token_write_dest
-        b = x.shape[0]
-        q, k, v = self._decode_qkv(p, x, cur_len)
+        q, k, v = self._qkv(p, x, cur_len[:, None])
         num_pages = kv[0].shape[0]
-        # write through the page table; unallocated pages / at-capacity
-        # sequences resolve to an out-of-bounds index and the scatter drops
-        # the write (the linear drop-at-capacity contract, paged)
-        dest = token_write_dest(page_table, cur_len, page_size, num_pages)
-        if len(kv) == 4:
-            kc, vc, ksc, vsc = kv
-            kq, k_s = _kv_quantize(k[:, 0], self.qcfg.kv_bits)
-            vq, v_s = _kv_quantize(v[:, 0], self.qcfg.kv_bits)
-            kv = (paged_token_write(kc, kq, dest),
-                  paged_token_write(vc, vq, dest),
-                  paged_token_write(ksc, k_s, dest),
-                  paged_token_write(vsc, v_s, dest))
-        else:
-            kc, vc = kv
-            kv = (paged_token_write(kc, k[:, 0], dest),
-                  paged_token_write(vc, v[:, 0], dest))
+        with jax.named_scope("kv_write"):
+            # write through the page table; unallocated pages / at-capacity
+            # sequences resolve to an out-of-bounds index and the scatter
+            # drops the write (the linear drop-at-capacity contract, paged)
+            dest = token_write_dest(page_table, cur_len, page_size,
+                                    num_pages)
+            if len(kv) == 4:
+                kc, vc, ksc, vsc = kv
+                kq, k_s = _kv_quantize(k[:, 0], self.qcfg.kv_bits)
+                vq, v_s = _kv_quantize(v[:, 0], self.qcfg.kv_bits)
+                kv = (paged_token_write(kc, kq, dest),
+                      paged_token_write(vc, vq, dest),
+                      paged_token_write(ksc, k_s, dest),
+                      paged_token_write(vsc, v_s, dest))
+            else:
+                kc, vc = kv
+                kv = (paged_token_write(kc, k[:, 0], dest),
+                      paged_token_write(vc, v[:, 0], dest))
         cap = page_table.shape[1] * page_size
-        out = ops.flash_decode(q, kv, jnp.minimum(cur_len + 1, cap),
-                               page_table=page_table,
-                               mode=self.kernel_mode)
-        x = x + self._mm(out.reshape(b, 1, -1), p["wo"])
-        x = x + self._mlp(p, x)
-        return x, kv
+        with jax.named_scope("attn.flash"):
+            out = ops.flash_decode(q, kv, jnp.minimum(cur_len + 1, cap),
+                                   page_table=page_table,
+                                   mode=self.kernel_mode)
+        return self._out_and_mlp(p, x, out), kv
 
     # ------------------------------------------------------------------
     # shared mlp half (prefill + decode)
