@@ -127,9 +127,10 @@ def pack_nibbles(codes: jax.Array) -> jax.Array:
     return (lo | (hi << 4)).astype(jnp.int8)
 
 
-def unpack_nibbles(packed: jax.Array) -> jax.Array:
-    """Inverse of :func:`pack_nibbles`: (..., D // 2) int8 -> (..., D) int32
-    with values sign-extended back to [-8, 7].
+def nibble_halves(packed: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The signed low and high nibbles of (..., D // 2) int8 bytes, each
+    (..., D // 2) int32 in [-8, 7]: byte ``j`` holds dims ``2j`` (low) and
+    ``2j + 1`` (high).
 
     Two arithmetic shifts per byte — the int8 -> int32 upcast already
     sign-extends bit 7, so ``>> 4`` yields the signed high nibble and
@@ -137,7 +138,13 @@ def unpack_nibbles(packed: jax.Array) -> jax.Array:
     inside the flash kernels' per-tile dequant epilogue.
     """
     xi = packed.astype(jnp.int32)
-    lo = (xi << 28) >> 28
-    hi = xi >> 4
+    return (xi << 28) >> 28, xi >> 4
+
+
+def unpack_nibbles(packed: jax.Array) -> jax.Array:
+    """Inverse of :func:`pack_nibbles`: (..., D // 2) int8 -> (..., D) int32
+    with values sign-extended back to [-8, 7] (:func:`nibble_halves`
+    interleaved along D)."""
+    lo, hi = nibble_halves(packed)
     d2 = packed.shape[-1]
     return jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], d2 * 2)

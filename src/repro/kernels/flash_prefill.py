@@ -30,9 +30,15 @@ Layout and grid:
                                   (scalar-prefetch; rows past it are pads)
 
     grid (B, ceil(S / block_kv))   — KV tiles innermost; ALL heads per
-                                     step (the block layout of
-                                     ``flash_decode``, for the same Mosaic
-                                     block rule)
+                                     step
+
+One grid step moves one KV tile of every head: the K/V block is
+``(1, block_kv, Hkv, Dk)``, so the last two block dims are the array's own
+``(Hkv, Dk)`` and Mosaic's (8, 128) block rule holds for any head count,
+head dim and tile (a per-head block would put a 1 in the second-minor
+place, which the TPU lowering refuses); the kv8 scale block is
+``(1, block_kv, Hkv)`` for the same reason.  The body loops over the heads
+and reads each head's ``(block_kv, Dk)`` slice with a strided VMEM load.
 
 The KV grid is **length-masked** on the chunk's end: tile ``t`` of sequence
 ``b`` only computes when ``t * block_kv < offset[b] + chunk_len[b]``, and
@@ -45,24 +51,25 @@ per-(row, position): position ``p`` is valid for row ``r`` iff
 
 Splitting invariance (the chunked-serving contract): for a fixed cache and
 tile size, each query row's online-softmax state walks the same KV tiles in
-the same order whether the row arrives in a C-token chunk, the whole-prompt
-"one big chunk", or a one-token decode step — trailing fully-masked tiles
-are exact no-ops (``exp(NEG_INF - m)`` underflows to 0 and ``corr`` is
-exactly 1).  Same-shape calls are BIT-identical (a 1-row chunk equals
-``flash_decode`` bit-for-bit — the preempt/resume contract); calls at
-*different* chunk sizes compile to differently-fused XLA graphs and agree
-to f32 ULPs, which is why the engine equivalence contract is stated as
-token identity.  ``ref.flash_prefill_ref`` is the tile-mirroring oracle;
+the same order whether the row arrives in a C-token chunk or the
+whole-prompt "one big chunk" — trailing fully-masked tiles are exact no-ops
+(``exp(NEG_INF - m)`` underflows to 0 and ``corr`` is exactly 1).  Calls at
+different chunk sizes compile to differently-fused XLA graphs and agree to
+f32 ULPs, which is why the engine equivalence contract is stated as token
+identity.  A one-token decode step walks the same tiles but runs
+``flash_decode``'s own per-page math (one query row per head, products on
+the VPU: ``flash_decode.attend_page``), so a 1-row chunk agrees with it to
+the same f32 ULPs, not bit for bit — the preempt/resume contract is token
+identity too.  ``ref.flash_prefill_ref`` is the tile-mirroring oracle;
 interpret mode is bit-identical to it under jit.
 
 Paged variant (``flash_prefill_paged``): the cache is the page pool of
 ``repro.serve.kv_cache`` — ``(num_pages, page_size, Hkv, D)`` plus
-per-sequence page tables — and the KV grid walks the page table exactly
-like ``flash_decode_paged`` (both scalars AND the table are scalar-prefetch
-operands; the page gather lives in the BlockSpec index map; one tile ==
-one page).  The kernel body is shared verbatim with the linear variant, and
-the per-(tile, head) math is ``flash_decode.attend_tile``, so neither the
-two layouts nor decode and prefill can drift.
+per-sequence page tables — and the KV grid walks the page table (both
+scalars AND the table are scalar-prefetch operands; the page gather lives
+in the BlockSpec index map; one tile == one page).  The kernel body is
+shared verbatim with the linear variant, and the per-(tile, head) math is
+:func:`attend_tile`, so the two layouts cannot drift.
 """
 from __future__ import annotations
 
@@ -73,12 +80,104 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_decode import (NEG_INF, VMEM_LIMIT, attend_tile,
-                                        bind_absent_scales, head_scales,
-                                        kv_format, kv_specs)
-from repro.kernels.quantize_pack import KV_BLOCK, kv4_check_head_dim
+from repro.kernels.flash_decode import NEG_INF, VMEM_LIMIT, kv_format
+from repro.kernels.quantize_pack import (KV_BLOCK, kv4_check_head_dim,
+                                         kv4_dequant)
 
 DEFAULT_BLOCK_KV = 512
+# f32 products on the MXU: at default precision XLA (the oracle on TPU)
+# and Mosaic would each round the operands to bf16 their own way
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def attend_tile(q, k, v, ks, vs, valid, m, l, acc, *, scale: float,
+                kv_bits: int):
+    """One online-softmax step of one head over one KV tile.
+
+    ``q`` (R, D) f32 query rows; ``k``/``v`` (block_kv, Dk) as stored;
+    ``ks``/``vs`` the head's scales — (block_kv, 1) f32 at kv8,
+    (block_kv, D//32) bf16 at kv4, None at kv16; ``valid`` a bool mask
+    broadcastable to (R, block_kv); ``m``/``l`` (R, 1) and ``acc`` (R, D)
+    the running state.  Returns the new ``(m, l, acc)``.  THE shared
+    per-tile math of the prefill kernel and its ref oracles, so the op
+    order cannot drift between them.  A chunk brings ``C·G`` rows per
+    head, so both products run on the MXU (decode's one row per head runs
+    :func:`repro.kernels.flash_decode.attend_page` on the VPU instead).
+    """
+    if kv_bits == 4:
+        # in-register nibble unpack + block-32 microscaling dequant
+        k = kv4_dequant(k, ks)
+        v = kv4_dequant(v, vs)
+    else:
+        k = k.astype(jnp.float32)
+        v = v.astype(jnp.float32)
+        if kv_bits == 8:
+            # int8 codes * per-(token, head) f32 scale
+            k = k * ks
+            v = v * vs
+    rows = q.shape[0]
+    if rows == 1:
+        # one query row: run it as two identical rows.  XLA's CPU backend
+        # turns a one-row dot into a multiply-reduce whose summation order
+        # depends on the surrounding fusion, which would break the
+        # interpret-vs-oracle bit-identity; the MXU pads rows to a full
+        # tile anyway, so the duplicate row is free on TPU
+        q, m, l, acc = (jnp.concatenate([x, x]) for x in (q, m, l, acc))
+    s = jax.lax.dot_general(
+        q, k, dimension_numbers=(((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32) * scale      # (R, block_kv)
+    s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc * corr + jax.lax.dot_general(
+        p, v, dimension_numbers=(((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32)
+    return m_new[:rows], l_new[:rows], acc_new[:rows]
+
+
+def head_scales(ks, vs, h: int, kv_bits: int, *lead):
+    """Head ``h``'s scale slices of a ``(*lead, block_kv, Hkv[, D//32])``
+    scale tile (array or VMEM ref) in the shape :func:`attend_tile`
+    takes."""
+    if kv_bits == 8:
+        idx = (*lead, slice(None), slice(h, h + 1))
+    elif kv_bits == 4:
+        idx = (*lead, slice(None), h, slice(None))
+    else:
+        return None, None
+    return ks[idx], vs[idx]
+
+
+def kv_specs(kv_bits, tile, hkv, d, kv_map, scale_map, k, v, k_scale,
+             v_scale):
+    """BlockSpecs + operands of a KV tile of every head — codes
+    ``(1, tile, Hkv, Dk)``, kv8 scales ``(1, tile, Hkv)``, kv4 block
+    scales ``(1, tile, Hkv, D//32)`` — shared by both layouts."""
+    dk = d // 2 if kv_bits == 4 else d
+    in_specs = [pl.BlockSpec((1, tile, hkv, dk), kv_map),
+                pl.BlockSpec((1, tile, hkv, dk), kv_map)]
+    args = [k, v]
+    if kv_bits == 4:
+        sspec = pl.BlockSpec((1, tile, hkv, d // KV_BLOCK), kv_map)
+        in_specs += [sspec, sspec]
+        args += [k_scale, v_scale]
+    elif kv_bits == 8:
+        sspec = pl.BlockSpec((1, tile, hkv), scale_map)
+        in_specs += [sspec, sspec]
+        args += [k_scale, v_scale]
+    return in_specs, args
+
+
+def bind_absent_scales(body, n_scalars: int):
+    """Keep one kernel body: bind the fp cache's absent scale refs to
+    None."""
+    def kernel(*refs):
+        scalars, rest = refs[:n_scalars], refs[n_scalars:]
+        qr, kr, vr, *tail = rest
+        return body(*scalars, qr, kr, vr, None, None, *tail)
+    return kernel
 
 
 def _kernel(offs_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,

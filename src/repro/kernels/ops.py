@@ -312,28 +312,31 @@ def flash_decode(q, kv, cur_len, *, scale=None, block_kv: Optional[int] = None,
     **Paged cache**: with ``page_table`` (B, max_pages_per_seq) int32, the
     kv entries are page *pools* — (num_pages, page_size, Hkv, D) codes and
     (num_pages, page_size, Hkv) scales — and the fused kernel walks the
-    page table (one KV tile == one page, gathered in the BlockSpec index
-    map; ``block_kv`` is ignored). ``ref`` runs
+    page table a block of pages per grid step (each page of all heads one
+    DMA per tensor, gathered in the BlockSpec index map, and one
+    online-softmax step of every head; ``block_kv`` is ignored). ``ref`` runs
     :func:`repro.kernels.ref.flash_decode_paged_ref` (bit-identical to
     interpret mode under jit); ``auto`` off-TPU gathers the table with XLA
     (``pool[page_table]``) and falls back to ``decode_attention`` — the one
     paged path that materializes the logical cache.
 
     Modes: ``pallas``/``interpret`` run the fused
-    :func:`repro.kernels.flash_decode.flash_decode` kernel — per-tile
-    in-register dequant, length-masked KV grid, no full-cache fp
+    :func:`repro.kernels.flash_decode.flash_decode` kernel — the linear
+    cache cut into pages of ``block_kv`` tokens and walked like a pool,
+    per-page in-register dequant, length-masked, no full-cache fp
     materialization. ``ref`` runs :func:`repro.kernels.ref.flash_decode_ref`,
-    the tile-mirroring oracle (bit-identical to interpret mode under jit;
-    still tile-at-a-time, so it also never materializes the full fp cache).
+    the page-walking oracle (bit-identical to interpret mode under jit;
+    still page-at-a-time, so it also never materializes the full fp cache).
     ``auto`` compiles the kernel on TPU and otherwise falls back to the
     portable :func:`repro.models.attention.decode_attention` XLA path —
     the one place the quantized cache is dequantized in full (CPU/GPU only;
     the fused path exists to avoid exactly that on TPU).
 
-    ``block_kv`` defaults to ``flash_decode.DEFAULT_BLOCK_KV`` and is
-    clamped to a single tile whenever S is not a block multiple (miniature
-    configs); head_dim needs no clamping — it is the innermost (lane)
-    dimension at any size.
+    ``block_kv`` (the linear cache's page) defaults to
+    ``flash_decode.DEFAULT_BLOCK_KV``; whenever S is not a multiple of it
+    (miniature configs, a ragged ``max_len``) the page is the largest
+    divisor of S below it (``flash_decode.linear_page``); head_dim needs
+    no clamping — it is the innermost (lane) dimension at any size.
     """
     k, v, k_scale, v_scale = _unpack_kv(kv)
     b, t, hq, d = q.shape
@@ -397,9 +400,9 @@ def _flash_decode_dispatch(q, k, v, k_scale, v_scale, cur_len, page_table,
         # softmax would otherwise emit the uniform mean of the slots)
         return jnp.where((cur_len > 0)[:, None, None, None], out,
                          jnp.zeros_like(out))
-    bkv = block_kv or fd.DEFAULT_BLOCK_KV
-    if bkv > s or s % bkv != 0:
-        bkv = s              # single tile (miniature / ragged max_len)
+    # the linear cache's page: whole pages of at most block_kv tokens
+    # (miniature / ragged max_len take the largest divisor of S)
+    bkv = fd.linear_page(s, block_kv or fd.DEFAULT_BLOCK_KV)
     q4 = q.reshape(b, hkv, hq // hkv, d)
     if impl == "ref":
         out = ref.flash_decode_ref(q4, k, v, cur_len, k_scale, v_scale,

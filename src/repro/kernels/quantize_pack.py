@@ -14,9 +14,11 @@ kv4 (MX-style microscaling, ``kv_bits=4``): :func:`kv4_quantize` packs K/V
 vectors into two int4 codes per byte along D with ONE bf16 scale per block
 of ``KV_BLOCK`` = 32 values — 2 B of scale per 32 values instead of the kv8
 layout's 4 B f32 per whole (token, head) row.  :func:`kv4_dequant` is the
-ONE unpack + block-scale epilogue shared verbatim by the flash kernel
-bodies, the tile-mirroring ref oracles, and the XLA fallbacks — sharing it
-is what keeps interpret mode bit-identical to ``ref`` at kv_bits=4.
+ONE unpack + block-scale epilogue shared verbatim by the flash-prefill
+kernel bodies, their tile-mirroring ref oracles, and the XLA fallbacks —
+sharing it is what keeps interpret mode bit-identical to ``ref`` at
+kv_bits=4; the decode kernel and its oracle share
+:func:`kv4_dequant_halves`, the same products without the interleave.
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.packing import pack_nibbles, unpack_nibbles
+from repro.core.packing import nibble_halves, pack_nibbles, unpack_nibbles
 
 KV_BLOCK = 32     # values sharing one bf16 microscaling scale
 KV4_QMAX = 7.0    # symmetric int4 grid: codes in [-8, 7]
@@ -66,14 +68,33 @@ def kv4_dequant(packed: jax.Array, scales: jax.Array) -> jax.Array:
     """In-register unpack + block-scale dequant: (..., D//2) int8 packed
     codes + (..., D//32) bf16 scales -> (..., D) float32.
 
-    THE shared kv4 epilogue: the flash kernel bodies run it on (block_kv,
-    D//2) tiles, the ref oracles on (B, block_kv, Hkv, D//2) slices, and
-    the XLA fallbacks on the whole cache — same elementwise op order
-    everywhere, so interpret mode stays bit-identical to ``ref``.
+    THE shared kv4 epilogue: the flash-prefill kernel bodies run it on
+    (block_kv, D//2) tiles, their ref oracles on (B, block_kv, Hkv, D//2)
+    slices, and the XLA fallbacks on the whole cache — same elementwise op
+    order everywhere, so interpret mode stays bit-identical to ``ref``.
     """
     codes = unpack_nibbles(packed)                       # (..., D) int32
     block_scale = jnp.repeat(scales.astype(jnp.float32), KV_BLOCK, axis=-1)
     return codes.astype(jnp.float32) * block_scale
+
+
+def kv4_dequant_halves(packed: jax.Array, scales: jax.Array) -> jax.Array:
+    """:func:`kv4_dequant` without the lane interleave: (..., D//2) int8
+    packed codes + (..., D//32) bf16 scales -> (2, ..., D//2) float32, the
+    even dims then the odd dims.  Byte ``j`` holds dims ``2j`` and
+    ``2j + 1``, both in block ``j // (KV_BLOCK // 2)``, so each value is
+    the same code times the same scale as in :func:`kv4_dequant`.  The
+    decode kernel's page of every head runs on the halves: Mosaic spends
+    minutes compiling the interleave of such a page, and VMEM runs out.
+    """
+    lo, hi = (x.astype(jnp.float32) for x in nibble_halves(packed))
+    block = jax.lax.broadcasted_iota(jnp.int32, packed.shape,
+                                     packed.ndim - 1) // (KV_BLOCK // 2)
+    sc = scales.astype(jnp.float32)
+    s = jnp.broadcast_to(sc[..., :1], packed.shape)
+    for b in range(1, scales.shape[-1]):
+        s = jnp.where(block == b, sc[..., b:b + 1], s)
+    return jnp.stack([lo * s, hi * s])
 
 
 def _pack_block(codes: jax.Array, bits: int) -> jax.Array:

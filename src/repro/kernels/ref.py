@@ -9,7 +9,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.packing import unpack
-from repro.kernels.flash_decode import NEG_INF, attend_tile, kv_format
+from repro.kernels.flash_decode import (NEG_INF, attend_page, finish,
+                                        kv_format, load_page, merge_dims,
+                                        split_dims)
+from repro.kernels.flash_prefill import attend_tile
 
 
 def dequant_matmul_ref(x: jax.Array, packed: jax.Array, scale: jax.Array,
@@ -99,9 +102,9 @@ def quant_matmul_ref(x: jax.Array, packed: jax.Array, scale: jax.Array,
 
 
 def _flash_grid(q, seq, n_tiles, fetch, live, valid, *, scale, kv_bits):
-    """Walk the flash kernels' grid in plain jnp, one sequence at a time:
+    """Walk the flash-prefill grid in plain jnp, one sequence at a time:
     KV tile ``t`` inner, every head per tile — the same
-    :func:`repro.kernels.flash_decode.attend_tile` per (tile, head) in the
+    :func:`repro.kernels.flash_prefill.attend_tile` per (tile, head) in the
     same order, with ``lax.cond`` standing in for the kernel's predicated
     tiles.  Walking one sequence per loop iteration (not a batch at once)
     is what keeps interpret mode BIT-identical to this under jit: XLA
@@ -179,41 +182,11 @@ def _paged_fetch(k_pool, v_pool, k_scale, v_scale):
     return fetch
 
 
-def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                     cur_len: jax.Array, k_scale=None, v_scale=None, *,
-                     scale=None, block_kv: int = 128,
-                     out_dtype=None) -> jax.Array:
-    """Tile-structured flash-decode oracle (the fused kernel's contract).
-
-    q (B, Hkv, G, D); k/v (B, S, Hkv, D) — kv8 int8 codes when 3D
-    ``k_scale`` / ``v_scale`` (B, S, Hkv) f32 are given, kv4 packed nibbles
-    (B, S, Hkv, D//2) when the scales are 4D (B, S, Hkv, D//32) bf16 block
-    grids, fp otherwise; cur_len (B,) valid positions.  Walks
-    ``flash_decode.flash_decode``'s grid op-for-op (:func:`_flash_grid`),
-    so the kernel in interpret mode is BIT-IDENTICAL to this under jit.
-    Positions ``>= cur_len[b]`` are masked; a zero-length row returns
-    zeros.
-    """
-    d = q.shape[-1]
-    s = k.shape[1]
-    assert s % block_kv == 0, (s, block_kv)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block_kv), 1)
-    out = _flash_grid(
-        q.astype(jnp.float32),
-        {"kv": (k, v, k_scale, v_scale), "cur": cur_len.astype(jnp.int32)},
-        s // block_kv, _linear_fetch(block_kv),
-        lambda a, t: t * block_kv < a["cur"],
-        lambda a, t: t * block_kv + iota < a["cur"],
-        scale=scale if scale is not None else d ** -0.5,
-        kv_bits=kv_format(k, k_scale, v_scale))
-    return out.astype(out_dtype or q.dtype)
-
-
 def flash_decode_paged_ref(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, page_table: jax.Array,
                            cur_len: jax.Array, k_scale=None, v_scale=None,
                            *, scale=None, out_dtype=None) -> jax.Array:
-    """Tile-mirroring oracle for the paged flash-decode kernel.
+    """Page-walking oracle for the flash-decode kernel (its contract).
 
     q (B, Hkv, G, D); ``k_pool``/``v_pool`` are page pools
     (num_pages, page_size, Hkv, Dk) — kv8 int8 codes (Dk = D) when
@@ -221,24 +194,78 @@ def flash_decode_paged_ref(q: jax.Array, k_pool: jax.Array,
     given, kv4 packed nibbles (Dk = D//2) when the scale pools are 4D
     (num_pages, page_size, Hkv, D//32) bf16, fp otherwise;
     ``page_table`` (B, max_pages_per_seq) int32 (−1 = unallocated);
-    ``cur_len`` (B,) valid positions.  One tile == one page: tile ``t``
-    gathers pool page ``page_table[b, t]`` and runs the grid of
-    ``flash_decode.flash_decode_paged`` op-for-op — interpret mode is
-    BIT-IDENTICAL to this under jit.  A zero-length row returns zeros.
+    ``cur_len`` (B,) valid positions.  One sequence per loop iteration,
+    its live pages in order, each gathered from the pool and run through
+    :func:`repro.kernels.flash_decode.attend_page` for every query row of
+    every head — the kernel's math, page for page, whatever its blocks —
+    so interpret mode is BIT-IDENTICAL to this under jit.  Walking one
+    sequence per iteration (not a batch at once) is what keeps it so: XLA
+    fuses, and so rounds, a batched loop body differently.  Only one page
+    is dequantized at a time — never the full cache.  A zero-length row
+    returns zeros.
     """
-    d = q.shape[-1]
+    bsz, hkv, g, d = q.shape
     ps = k_pool.shape[1]
-    cur = cur_len.astype(jnp.int32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    out = _flash_grid(
-        q.astype(jnp.float32),
-        {"pt": page_table.astype(jnp.int32), "total": cur},
-        page_table.shape[1], _paged_fetch(k_pool, v_pool, k_scale, v_scale),
-        lambda a, t: t * ps < a["total"],
-        lambda a, t: t * ps + iota < a["total"],
-        scale=scale if scale is not None else d ** -0.5,
-        kv_bits=kv_format(k_pool, k_scale, v_scale))
-    return out.astype(out_dtype or q.dtype)
+    kv_bits = kv_format(k_pool, k_scale, v_scale)
+    scale = scale if scale is not None else d ** -0.5
+    pools = (k_pool, v_pool, k_scale, v_scale)
+
+    def one(xs):
+        qb, pt, cur = xs
+        qs = [qb[gi] * scale for gi in range(g)]
+        acc0 = jnp.zeros(qb.shape, jnp.float32)
+
+        def page(t, st):
+            def update(st):
+                idx = jnp.maximum(pt[t], 0)
+                page = load_page(*(None if x is None else x[idx]
+                                   for x in pools), kv_bits=kv_bits)
+                new = [attend_page(qs[gi], page, cur - t * ps,
+                                   *(x[gi] for x in st))
+                       for gi in range(g)]
+                return tuple(jnp.stack(x) for x in zip(*new))
+            return jax.lax.cond(t * ps < cur, update, lambda st: st, st)
+
+        init = (jnp.full((g, hkv, 1), NEG_INF, jnp.float32),
+                jnp.zeros((g, hkv, 1), jnp.float32), acc0)
+        _, l, acc = jax.lax.fori_loop(0, pt.shape[0], page, init)
+        return finish(acc, l)
+
+    qg = jnp.swapaxes(q, 1, 2).astype(jnp.float32)         # (B, G, Hkv, D)
+    if kv_bits == 4:
+        qg = split_dims(qg)
+    out = jax.lax.map(one, (qg, page_table.astype(jnp.int32),
+                            cur_len.astype(jnp.int32)))
+    if kv_bits == 4:
+        out = merge_dims(out)
+    return jnp.swapaxes(out, 1, 2).astype(out_dtype or q.dtype)
+
+
+def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                     cur_len: jax.Array, k_scale=None, v_scale=None, *,
+                     scale=None, block_kv: int = 128,
+                     out_dtype=None) -> jax.Array:
+    """Linear-cache oracle of the flash-decode kernel (its contract).
+
+    q (B, Hkv, G, D); k/v (B, S, Hkv, D) — kv8 int8 codes when 3D
+    ``k_scale`` / ``v_scale`` (B, S, Hkv) f32 are given, kv4 packed nibbles
+    (B, S, Hkv, D//2) when the scales are 4D (B, S, Hkv, D//32) bf16 block
+    grids, fp otherwise; cur_len (B,) valid positions.  Each ``block_kv``
+    tile is one page: the cache is the pool of its tiles under the
+    identity page table, as in ``flash_decode.flash_decode``, walked by
+    :func:`flash_decode_paged_ref` — so the kernel in interpret mode is
+    BIT-IDENTICAL to this under jit.  Positions ``>= cur_len[b]`` are
+    masked; a zero-length row returns zeros.
+    """
+    bsz, s = k.shape[0], k.shape[1]
+    assert s % block_kv == 0, (s, block_kv)
+    n = s // block_kv
+    pools = [None if x is None else x.reshape(bsz * n, block_kv, *x.shape[2:])
+             for x in (k, v, k_scale, v_scale)]
+    table = jnp.arange(bsz * n, dtype=jnp.int32).reshape(bsz, n)
+    return flash_decode_paged_ref(q, pools[0], pools[1], table, cur_len,
+                                  pools[2], pools[3], scale=scale,
+                                  out_dtype=out_dtype)
 
 
 def _prefill_masks(offset, chunk_len, c, g, tile):

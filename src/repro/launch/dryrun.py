@@ -201,11 +201,11 @@ def _lower_quantized_decode(cfg, sc, mesh, rules, chips, variant, *,
     weight-activation path; ``kv_bits < 16`` the int8-coded KV cache —
     both native ``QuantizedModel`` features, no spec stubbing needed.
 
-    ``kernel_mode="ref"`` makes decode attention lower the tile-structured
-    flash-decode reference (``ops.flash_decode`` mode ref): 64 KV tiles of
-    512 slots for the 32k shapes, cache read as stored, no (B, S, Hkv, D)
-    fp intermediate in the step — the same loop structure the Pallas kernel
-    executes per (batch, head) on TPU."""
+    ``kernel_mode="ref"`` makes decode attention lower the page-structured
+    flash-decode reference (``ops.flash_decode`` mode ref): 2048 pages of
+    16 slots for the 32k shapes, cache read as stored, no (B, S, Hkv, D)
+    fp intermediate in the step — the same per-page math the Pallas kernel
+    runs for every head of a sequence on TPU."""
     from repro.core.quantizer import QuantConfig
     from repro.serve.quantized import QuantizedModel, quantize_lm_packed
 

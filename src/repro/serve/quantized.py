@@ -175,8 +175,9 @@ def _kv_quantize(x: jax.Array, kv_bits: int
 class QuantizedModel:
     """Model-compatible wrapper serving QTensor trees (dense/MoE).
 
-    ``flash_block_kv`` overrides the flash-decode KV tile size (None =
-    kernel default, clamped to a single tile for miniature caches)."""
+    ``flash_block_kv`` overrides the flash kernels' KV tile size over a
+    linear cache — decode's page, prefill's tile (None = each kernel's
+    default, cut down to fit miniature caches)."""
     cfg: ModelConfig
     qcfg: QuantConfig
     kernel_mode: str = "auto"
@@ -426,8 +427,8 @@ class QuantizedModel:
                 kc, vc = kv
                 kv = (write(kc, k), write(vc, v))
         # attention reads the cache AS STORED (prefix + this chunk):
-        # in-register tile dequant, chunk-end-masked KV grid — bit-identical
-        # per row to flash_decode over the same cache (resume exactness)
+        # in-register tile dequant, chunk-end-masked KV grid — per row the
+        # same result as flash_decode over the same cache, to f32 ULPs
         with jax.named_scope("attn.flash"):
             out = ops.flash_prefill(q, kv, offset, chunk_len,
                                     block_kv=self.flash_block_kv,

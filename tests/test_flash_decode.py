@@ -20,6 +20,7 @@ import pytest
 import kernel_conformance as kc
 from repro.configs import get_config
 from repro.core.quantizer import QuantConfig
+from repro.kernels import flash_decode as fd
 from repro.kernels import ops
 from repro.models import build_model
 from repro.serve.quantized import QuantizedModel, quantize_lm_packed
@@ -27,15 +28,31 @@ from repro.serve.quantized import QuantizedModel, quantize_lm_packed
 
 @pytest.mark.parametrize("kv_bits", kc.KV_BITS)
 @pytest.mark.parametrize("g", kc.GQA_GROUPS)
-@pytest.mark.parametrize("block_kv", kc.KV_BLOCKS)
-def test_flash_decode_interpret_bit_identical_to_ref(kv_bits, g, block_kv):
+@pytest.mark.parametrize("block_kv,rows", [
+    *(pytest.param(n, "ragged", id=str(n)) for n in kc.KV_BLOCKS),
+    *(pytest.param(n, "block_edges", id=f"{n}-block_edges")
+      for n in kc.KV_BLOCKS)])
+def test_flash_decode_interpret_bit_identical_to_ref(kv_bits, g, block_kv,
+                                                     rows):
     """Ragged cur_len in one batch: near-empty, mid-tile, and full-cache
-    rows all run through the length-masked grid bit-identically."""
+    rows; or rows at the edges of the kernel's blocks of tiles (empty,
+    one token, a tile boundary, a block boundary and one past it, a tile
+    count no multiple of the block) — all bit-identical through the
+    length-masked grid."""
     b, s, hkv, d = 3, 64, 2, 32
+    cur = [1, 37, s]
+    if rows == "block_edges":
+        ppb = max(1, fd.BLOCK_TOKENS // (block_kv * g))
+        s = (2 * ppb + 1) * block_kv
+        cur = [0, 1, block_kv, ppb * block_kv, ppb * block_kv + 1, s - 5]
+        b = len(cur)
     key = jax.random.PRNGKey(kv_bits * 10 + g)
     q, kv, _ = kc.make_cache_inputs(key, b, s, hkv, g, d, kv_bits)
-    cur = jnp.array([1, 37, s], jnp.int32)
-    kc.assert_interpret_matches_ref(ops.flash_decode, q, kv, cur,
+    if rows == "block_edges":
+        pools = [x.reshape(-1, block_kv, *x.shape[2:]) for x in kv]
+        assert fd.pages_per_block(pools, g, s // block_kv) == ppb
+    kc.assert_interpret_matches_ref(ops.flash_decode, q, kv,
+                                    jnp.array(cur, jnp.int32),
                                     static=dict(block_kv=block_kv))
 
 
@@ -87,12 +104,39 @@ def test_flash_decode_zero_length_rows_return_zeros():
 
 
 def test_flash_decode_clamps_block_to_ragged_max_len():
-    """S=56 is no multiple of any default block: the dispatcher clamps to a
-    single tile and still matches the fallback."""
+    """S=56 is no multiple of the default page: the dispatcher cuts it into
+    14-token pages, the largest that divide it, and still matches the
+    fallback."""
     b, s, hkv, g, d = 2, 56, 2, 2, 16
+    assert fd.linear_page(s, fd.DEFAULT_BLOCK_KV) == 14
     q, kv, _ = kc.make_cache_inputs(jax.random.PRNGKey(2), b, s, hkv, g, d, 8)
     cur = jnp.array([5, 56], jnp.int32)
     kc.assert_matches_fallback(ops.flash_decode, q, kv, cur)
+
+
+@pytest.mark.parametrize("s,page", [(53, 1), (1208, 8)])
+def test_flash_decode_ragged_max_len_cuts_whole_pages(s, page):
+    """A prime S and a 1000 + 200 token run's S (no multiple of 16) cut
+    into whole pages no longer than the default, never one page of the
+    whole cache; the block stays within its slot cap, and the result is
+    the oracle's bit for bit and the fallback's to fp tolerance."""
+    b, hkv, g, d = 2, 2, 1, 16
+    assert fd.linear_page(s, fd.DEFAULT_BLOCK_KV) == page
+    q, kv, _ = kc.make_cache_inputs(jax.random.PRNGKey(s), b, s, hkv, g, d, 8)
+    pools = [x.reshape(-1, page, *x.shape[2:]) for x in kv]
+    assert fd.pages_per_block(pools, g, s // page) == fd.MAX_PAGES_PER_BLOCK
+    cur = jnp.array([s - page - 1, s], jnp.int32)
+    kc.assert_interpret_matches_ref(ops.flash_decode, q, kv, cur)
+    kc.assert_matches_fallback(ops.flash_decode, q, kv, cur)
+
+
+def test_pages_per_block_refuses_page_over_vmem():
+    """A page whose double-buffered pools alone pass the block's VMEM
+    budget is refused, not run as a block of one page."""
+    page = jax.ShapeDtypeStruct((4, 4096, 40, 128), jnp.int8)
+    scales = jax.ShapeDtypeStruct((4, 4096, 40), jnp.float32)
+    with pytest.raises(AssertionError, match="BLOCK_VMEM"):
+        fd.pages_per_block([page, page, scales, scales], 1, 4)
 
 
 def test_flash_decode_rejects_bad_inputs():

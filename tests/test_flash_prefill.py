@@ -151,12 +151,13 @@ def test_prefill_splitting_invariance():
     online-softmax state, so a row's result does not depend on which chunk
     delivered it.
 
-    The per-tile math is identical, but XLA re-fuses the graph per chunk
-    SHAPE, so only same-shape comparisons are bit-exact: a 1-row prefill
-    chunk vs the 1-token decode kernel (the preempt/resume and
-    chunk-boundary contract) is BIT-identical, while cross-chunk-size
+    XLA re-fuses the graph per chunk SHAPE, so cross-chunk-size
     comparisons (the whole-vs-chunked engine contract) agree to float32
-    ULPs — far below any argmax gap, hence token-identical engines."""
+    ULPs — far below any argmax gap, hence token-identical engines.  A
+    1-row prefill chunk vs the 1-token decode kernel (the preempt/resume
+    and chunk-boundary contract) agrees to the same ULPs: decode sums q·k
+    and P·V on the VPU in its own order (``attend_page``), prefill on the
+    MXU (``attend_tile``), by design."""
     b, s, hkv, g, d, c = 2, 64, 2, 2, 16, 8
     q, kv, _ = kc.make_cache_inputs(jax.random.PRNGKey(2), b, s, hkv, g, d,
                                     8, chunk=c)
@@ -175,10 +176,10 @@ def test_prefill_splitting_invariance():
     for i in range(c):
         row = run(q[:, i:i + 1], off + i, one)
         np.testing.assert_allclose(y_whole[:, i:i + 1], row, **ulps)
-        # same shapes -> same compiled graph -> BIT-identical to decode
+        # decode's per-page op order is its own: f32 ULPs, like chunk sizes
         dec = np.asarray(ops.flash_decode(q[:, i:i + 1], kv, off + i + 1,
                                           mode="interpret", block_kv=16))
-        np.testing.assert_array_equal(row, dec)
+        np.testing.assert_allclose(row, dec, **ulps)
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ Contract under test (DESIGN.md §9):
     (jaxpr traversal; the gather fallback is the positive control).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ import pytest
 import kernel_conformance as kc
 from repro.configs import get_config
 from repro.core.quantizer import QuantConfig
+from repro.kernels import flash_decode as fd
 from repro.kernels import ops
 from repro.models import build_model
 from repro.serve import kv_cache as kvc
@@ -36,21 +38,66 @@ from repro.serve.quantized import QuantizedModel, quantize_lm_packed
 # kernel parity (the acceptance sweep)
 # ---------------------------------------------------------------------------
 
+def _block_edge_lens(page_size, g):
+    """Lengths at the edges of the kernel's page blocks: empty, one token,
+    a page boundary, a block boundary and one past it, and a row whose
+    live pages are no multiple of the block — with the block size the
+    kernel picks for these shapes (asserted, so the rows do span blocks)."""
+    ppb = max(1, fd.BLOCK_TOKENS // (page_size * g))
+    lens = [0, 1, page_size, ppb * page_size, ppb * page_size + 1,
+            (2 * ppb + 1) * page_size - 5]
+    return lens, ppb
+
+
 @pytest.mark.parametrize("kv_bits", kc.KV_BITS)
 @pytest.mark.parametrize("g", kc.GQA_GROUPS)
-@pytest.mark.parametrize("page_size", kc.KV_BLOCKS)
-def test_paged_interpret_bit_identical_to_ref(kv_bits, g, page_size):
+@pytest.mark.parametrize("page_size,rows", [
+    *(pytest.param(n, "ragged", id=str(n)) for n in kc.KV_BLOCKS),
+    *(pytest.param(n, "block_edges", id=f"{n}-block_edges")
+      for n in kc.KV_BLOCKS)])
+def test_paged_interpret_bit_identical_to_ref(kv_bits, g, page_size, rows):
     """Ragged cur_len in one batch — empty row, single token, exact page
-    boundary, and a mid-page tail — all bit-identical through the
-    page-table-walking grid."""
+    boundary, and a mid-page tail; or rows at the edges of the kernel's
+    page blocks — all bit-identical through the page-table-walking grid
+    (shuffled pages, a -1 tail past each row's own)."""
     b, hkv, d = 4, 2, 32
     lens = [0, 1, page_size, 2 * page_size + 7]
+    if rows == "block_edges":
+        lens, ppb = _block_edge_lens(page_size, g)
+        b = len(lens)
     key = jax.random.PRNGKey(kv_bits * 10 + g + page_size)
     q, kv, pt, _ = kc.make_paged_inputs(key, b, hkv, g, d, page_size, lens,
                                         kv_bits)
     cur = jnp.asarray(lens, jnp.int32)
+    if rows == "block_edges":
+        assert fd.pages_per_block(kv, g, pt.shape[1]) == ppb
     kc.assert_interpret_matches_ref(ops.flash_decode, q, kv, cur,
                                     page_table=pt)
+
+
+@pytest.mark.parametrize("kv_bits", kc.KV_BITS)
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_paged_result_independent_of_pages_per_block(kv_bits, g):
+    """The block size is the kernel's own choice, not part of its result:
+    one, three and the chosen number of pages a grid step give the same
+    bits, since pages update the online softmax one at a time in order."""
+    b, hkv, d, ps = 4, 2, 32, 16
+    lens = [0, 1, 5 * ps, 7 * ps + 3]
+    q, kv, pt, _ = kc.make_paged_inputs(jax.random.PRNGKey(40 + g), b, hkv,
+                                        g, d, ps, lens, kv_bits)
+    cur = jnp.asarray(lens, jnp.int32)
+    q4 = q.reshape(b, hkv, g, d)
+    k, v, ks, vs = kv if len(kv) == 4 else (*kv, None, None)
+
+    def run(ppb):
+        return np.asarray(jax.jit(functools.partial(
+            fd._flash_decode_pages, scale=None, ppb=ppb, name="flash_decode",
+            interpret=True))(q4, k, v, pt, cur, ks, vs))
+
+    one = run(1)
+    np.testing.assert_array_equal(one, run(3))
+    np.testing.assert_array_equal(
+        one, run(fd.pages_per_block(kv, g, pt.shape[1])))
 
 
 @pytest.mark.parametrize("kv_bits", kc.KV_BITS)

@@ -12,6 +12,8 @@ only one process may load the TPU library, and under several test workers
 only the worker that runs this file loads it.  Every compile stays in this
 one file for the same reason.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -53,6 +55,17 @@ def compile_tpu(one_chip):
     yield run
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def _custom_call_names(hlo):
+    """The instruction names of an HLO text's ``tpu_custom_call``s, without
+    their ``.N`` suffix: the names a device trace keys kernel time on."""
+    names = set()
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            lhs = line.split(" = ", 1)[0].replace("ROOT ", "").strip()
+            names.add(re.sub(r"\.\d+$", "", lhs.lstrip("%")))
+    return names
 
 
 def _sds(shape, dtype):
@@ -97,20 +110,28 @@ def test_dequant_matmul_w4_compiles(compile_tpu):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("kv_bits,page_size,heads", [
-    (8, 16, HEADS), (8, 64, HEADS), (4, 16, HEADS),
-    (8, 16, HEADS // 4)])   # one chip's head slice of a (1, 4) mesh
-def test_flash_decode_paged_compiles(compile_tpu, kv_bits, page_size, heads):
-    """Paged decode at the engine's page sizes: one tile == one page, all
-    heads of a page in one block."""
-    num_pages = BATCH * PAGES_PER_SEQ
+@pytest.mark.parametrize("kv_bits,page_size,heads,batch,pages", [
+    pytest.param(8, 16, HEADS, BATCH, PAGES_PER_SEQ, id="8-16-32"),
+    pytest.param(8, 64, HEADS, BATCH, PAGES_PER_SEQ, id="8-64-32"),
+    pytest.param(4, 16, HEADS, BATCH, PAGES_PER_SEQ, id="4-16-32"),
+    # one chip's head slice of a (1, 4) mesh
+    pytest.param(8, 16, HEADS // 4, BATCH, PAGES_PER_SEQ, id="8-16-8"),
+    # the benchmark's pools: OPT-6.7B decode and OPT-13B long context
+    pytest.param(8, 16, 32, 16, 56, id="8-16-32-b16-p56"),
+    pytest.param(8, 16, 40, 4, 112, id="8-16-40-b4-p112"),
+    pytest.param(8, 16, 8, 16, 56, id="8-16-8-b16-p56")])
+def test_flash_decode_paged_compiles(compile_tpu, kv_bits, page_size, heads,
+                                     batch, pages):
+    """Paged decode at the engine's page sizes: blocks of pages, each page
+    of all heads one BlockSpec slot, under the kernel's own name."""
+    num_pages = batch * pages
     hlo = compile_tpu(
         lambda q, kv, pt, n: ops.flash_decode(q, kv, n, page_table=pt,
                                               mode="pallas"),
-        _sds((BATCH, 1, heads, HEAD_DIM), jnp.bfloat16),
+        _sds((batch, 1, heads, HEAD_DIM), jnp.bfloat16),
         _kv_pools(num_pages, page_size, kv_bits, heads),
-        _sds((BATCH, PAGES_PER_SEQ), jnp.int32), _sds((BATCH,), jnp.int32))
-    assert "tpu_custom_call" in hlo
+        _sds((batch, pages), jnp.int32), _sds((batch,), jnp.int32))
+    assert "flash_decode_paged" in _custom_call_names(hlo)
 
 
 def test_flash_prefill_paged_compiles(compile_tpu):
@@ -126,16 +147,28 @@ def test_flash_prefill_paged_compiles(compile_tpu):
     assert "tpu_custom_call" in hlo
 
 
-def test_flash_decode_linear_compiles(compile_tpu):
-    """Linear kv8 cache, 512-token tiles."""
-    s = 1024
-    codes = _sds((BATCH, s, HEADS, HEAD_DIM), jnp.int8)
-    scales = _sds((BATCH, s, HEADS), jnp.float32)
-    hlo = compile_tpu(
+def _linear_decode_hlo(compile_tpu, batch, s):
+    codes = _sds((batch, s, HEADS, HEAD_DIM), jnp.int8)
+    scales = _sds((batch, s, HEADS), jnp.float32)
+    return compile_tpu(
         lambda q, kv, n: ops.flash_decode(q, kv, n, mode="pallas"),
-        _sds((BATCH, 1, HEADS, HEAD_DIM), jnp.bfloat16),
-        (codes, codes, scales, scales), _sds((BATCH,), jnp.int32))
+        _sds((batch, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+        (codes, codes, scales, scales), _sds((batch,), jnp.int32))
+
+
+def test_flash_decode_linear_compiles(compile_tpu):
+    """Linear kv8 cache, cut into 16-token pages."""
+    hlo = _linear_decode_hlo(compile_tpu, BATCH, 1024)
     assert "tpu_custom_call" in hlo
+
+
+def test_flash_decode_linear_ragged_compiles(compile_tpu):
+    """A linear kv8 cache of a ragged length (a 1000 + 200 token run's
+    ``max_len``): 8-token pages, the largest that divide it, never one
+    page of the whole cache."""
+    hlo = _linear_decode_hlo(compile_tpu, 4, 1208)
+    assert "flash_decode" in _custom_call_names(hlo)
+    assert "s8[604,8,32,128]" in hlo       # 4 rows x 151 pages of 8 tokens
 
 
 def test_quantize_pack_compiles(compile_tpu):
